@@ -1,24 +1,22 @@
-"""Fig 9 (beyond-paper): per-kernel records for the fused connectivity rounds.
+"""Fig 9 (beyond-paper): per-kernel records for the connectivity rounds.
 
-The tentpole perf claim of the kernel subsystem is BYTE TRAFFIC, not wall
-time: one Borůvka (or SFS frontier) round must stream the edge buffer ONCE
-(9 B/edge) where the three-pass lax sequence re-reads it through two
-``segment_min`` passes (25 B/edge; 50 for the 2E-arc frontier round). Wall
-times on shared CI runners are noise; the byte counters come from the
-analytic traffic model in ``repro.kernels.boruvka_round.ops`` and are
+The byte counters come from the analytic traffic model in
+``repro.kernels.boruvka_round.ops`` (9 B/edge "fused" vs 25 B/edge for
+the three-pass lax sequence; 50 for the 2E-arc frontier round) and are
 deterministic for a fixed world, so ``scripts/check_bench.py`` pins them
 EXACTLY (``bytes_fused=``/``bytes_lax=``), alongside the measured Borůvka
-round count (``boruvka_rounds=``) of the fixed planted world. The ≤½ bound
-— fused moves at most half the lax bytes — is asserted inline, so the
-bench run itself fails if the byte model regresses.
+round count (``boruvka_rounds=``) of the fixed planted world. The model
+predates the kernels' current form (XLA gathers ahead of them, one pass
+per segment tile), so the "fused" figure is a model, not what the kernels
+read (DESIGN.md §Kernels). Wall times on shared CI runners are noise.
 
 Per kernel, two timed operating points on the same world:
 
-  * auto   — the dispatched production path (``use_pallas=None``): the
-    fused Pallas kernel on TPU, the jnp oracle on CPU CI. The ``path=``
-    token records which, so numbers are attributable to a code path.
-  * oracle — the pre-fusion three-pass lax sequence (``use_pallas=False``),
-    the baseline the fused path replaces.
+  * auto   — the dispatched production path (``use_pallas`` unset): the
+    XLA path on every platform. The ``path=`` token records which, so
+    numbers are attributable to a code path.
+  * oracle — the same XLA sequence requested by name
+    (``use_pallas=False``).
 
 A closing parity sanity check runs the interpret-mode kernels against the
 oracles on a small multigraph buffer — the smoke run refuses to report

@@ -26,11 +26,10 @@ bridges (the paper's workload; int-vector ops counted as FLOP-equivalents):
   merge phases: log2(M) * 2 * log2(V) * 4(V-1) * ~8
   final PRAM bridges: ~40 * V * log2(V)
   collective bytes (exact by construction): log2(M) phases * 2(V-1) * 9 B.
-  Memory traffic per round-scanned edge slot depends on the kernel path:
-  the fused ``boruvka_round`` kernel streams the raw buffer once
-  (9 B/edge/round); the three-pass lax baseline re-reads it through two
-  ``segment_min`` passes (25 B/edge/round) — ``fused=`` selects the term
-  (byte model: repro.kernels.boruvka_round.ops, pinned by fig9).
+  Memory traffic per round-scanned edge slot: the "fused" byte model
+  (9 B/edge/round) or the three-pass lax sequence (25 B/edge/round) —
+  ``fused=`` selects the term (byte model: repro.kernels.boruvka_round.ops,
+  pinned by fig9; it no longer matches the kernels, DESIGN.md §Kernels).
 """
 from __future__ import annotations
 
@@ -110,9 +109,8 @@ def bridges_model(shape: dict, m: int, merge: str = "recertify",
     ``merge='incremental'`` models the warm-start merge: per phase the two
     delta passes scan only the received 2(n-1) buffer (rounds_merge is then
     the measured f1+f2 DELTA rounds) plus one 4(n-1) concat+compact.
-    ``fused`` selects the per-round edge-scan traffic: the fused
-    boruvka_round kernel (9 B/edge/round, the default production path) vs
-    the three-pass lax baseline (25 B/edge/round).
+    ``fused`` selects the per-round edge-scan traffic: the "fused" byte
+    model (9 B/edge/round) vs the three-pass lax sequence (25 B/edge/round).
     """
     v, e = shape["n_nodes"], shape["n_edges"]
     worst = math.ceil(math.log2(v))

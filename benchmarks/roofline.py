@@ -78,9 +78,10 @@ def build_table(recs, mesh_kind: str = "single"):
         if spec.family == "graph":
             # analytic model supplies the honest terms (HLO counts loop
             # bodies once for the data-dependent Borůvka rounds); memory
-            # term is the FUSED boruvka_round path (9 B/edge/round — the
-            # production kernel); the lax 25 B/edge term is kept for the
-            # delta note so the kernel's roofline shift stays visible
+            # term is the "fused" byte model (9 B/edge/round), which no
+            # longer matches the kernels or the XLA path the chip runs
+            # (DESIGN.md §Kernels); the lax 25 B/edge term is kept for
+            # the delta note
             am = bridges_model(spec.shapes[shape], n_chips, fused=True)
             am_lax = bridges_model(spec.shapes[shape], n_chips, fused=False)
             t_c = am["model_ops"] / (HW["peak_flops"] / 2)  # int ops on VPU

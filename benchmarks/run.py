@@ -59,6 +59,9 @@ def main() -> None:
     args = ap.parse_args()
     which = set(args.only.split(",")) if args.only else None
 
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         fig10_serving,
         fig11_failover,

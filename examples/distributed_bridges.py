@@ -1,14 +1,16 @@
-"""The paper's full distributed pipeline on an 8-device host mesh:
+"""The paper's full distributed pipeline on a mesh of every device:
 partition -> per-machine sparse certificates -> log-phase merge ->
-bridge extraction, all one XLA program.
+bridge extraction, all one XLA program. On a TPU host the machines are
+the chips; on the CPU, 8 virtual devices stand in for them.
 
-    PYTHONPATH=src python examples/distributed_bridges.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/distributed_bridges.py
+    PYTHONPATH=src python examples/distributed_bridges.py   # TPU host
 """
 import os
 
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
-)
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               + os.environ.get("XLA_FLAGS", ""))
 
 import time
 
@@ -21,7 +23,8 @@ from repro.graph import generators as gen
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("machines",), axis_types=(AxisType.Auto,))
+    n_dev = len(jax.devices())
+    mesh = jax.make_mesh((n_dev,), ("machines",), axis_types=(AxisType.Auto,))
     n, m = 3_000, 150_000
     src, dst, planted = gen.planted_bridge_graph(n, m, n_bridges=8, seed=7)
     print(f"|V|={n} |E|={len(src)} on M={mesh.devices.size} machines")
@@ -30,7 +33,8 @@ def main():
     for schedule in ("paper", "xor", "hierarchical"):
         axes = ("machines",)
         if schedule == "hierarchical":
-            mesh2 = jax.make_mesh((4, 2), ("data", "model"),
+            mesh2 = jax.make_mesh((max(n_dev // 2, 1), min(n_dev, 2)),
+                                  ("data", "model"),
                                   axis_types=(AxisType.Auto,) * 2)
             t0 = time.time()
             got = find_bridges(src, dst, n, mesh=mesh2,

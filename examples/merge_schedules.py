@@ -1,16 +1,18 @@
 """Compare the paper's merge schedule against the beyond-paper variants.
 
-Runs the full distributed pipeline on 8 simulated devices for every
-(schedule x merge) combination, verifies all six give IDENTICAL bridges,
-and prints CPU wall time per variant (shape only — the roofline terms in
-EXPERIMENTS.md are the performance claims).
+Runs the full distributed pipeline on a (2, M/2) mesh of every device for
+every (schedule x merge) combination, verifies all six give IDENTICAL
+bridges, and prints wall time per variant, compilation included. On the
+CPU, 8 virtual devices stand in for the machines.
 
-    PYTHONPATH=src python examples/merge_schedules.py
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/merge_schedules.py
+    PYTHONPATH=src python examples/merge_schedules.py   # TPU host
 """
 import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=8 " + os.environ.get("XLA_FLAGS", "")
-)
+
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                               + os.environ.get("XLA_FLAGS", ""))
 
 import time
 
@@ -28,8 +30,9 @@ def main():
     want = bridges_dfs(src, dst, n)
     print(f"graph: |V|={n} |E|={len(src)}; oracle bridges: {len(want)}")
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         axis_types=(AxisType.Auto,) * 2)
+    n_dev = len(jax.devices())
+    mesh = jax.make_mesh((min(n_dev, 2), max(n_dev // 2, 1)),
+                         ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     for schedule in ("paper", "xor", "hierarchical"):
         for merge in ("recertify", "incremental"):
             t0 = time.time()

@@ -103,8 +103,8 @@ def euler_tour(tsrc, tdst, tmask, labels, n: int):
     # so disc[v] = 1 + min entering-arc position. Roots are discovered at the
     # position of their first outgoing arc (their component offset). This keeps
     # discovery times unique: root=offset, first child=offset+1, ...
-    # kernel-backed segment_min (repro.kernels.segment_min): Pallas on TPU,
-    # the jnp scatter-min oracle elsewhere — same INF32-for-empty contract
+    # repro.kernels.segment_min: the XLA scatter-min (its Pallas kernel
+    # only where a caller asks) — INF32 for empty segments
     disc = segment_min(
         jnp.where(amask, gpos, INF32), jnp.where(amask, arc_dst, 0), n
     )

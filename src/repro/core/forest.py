@@ -5,8 +5,8 @@ Borůvka-style minimum-edge hooking with pointer-doubling contraction:
 
   repeat O(log V) times:
     1. every component picks its minimum-index incident cross edge
-       (the fused ``boruvka_round`` reduction over both endpoints'
-       component labels — one streamed pass over the edge buffer)
+       (the ``boruvka_round`` reduction over both endpoints' component
+       labels)
     2. components hook along the picked edge; mutual 2-cycles (the only
        possible cycles under distinct edge keys) are broken by id order
     3. labels are flattened by pointer doubling
@@ -16,12 +16,12 @@ indices act as distinct weights, so the classic Borůvka argument gives an
 acyclic, component-spanning edge set.
 
 Both hooking loops dispatch their per-round edge scan through
-``repro.kernels.boruvka_round`` (DESIGN.md §Kernels): the fused Pallas
-kernel on TPU, the jnp oracle elsewhere, with ``use_pallas=True`` forcing
-the kernel (interpret mode off-TPU) for parity testing. The knob threads
-through every public entry point here, so certificates — and through the
-certificate registry, every engine substrate — inherit the fused path
-with zero engine edits.
+``repro.kernels.boruvka_round`` (DESIGN.md §Kernels): the XLA path on
+every platform, with ``use_pallas=True`` selecting the Pallas kernel
+(compiled on a TPU, interpreted elsewhere) for parity checks. The knob
+threads through every public entry point here, so certificates — and
+through the certificate registry, every engine substrate — take the same
+path.
 """
 from __future__ import annotations
 
@@ -90,7 +90,7 @@ def _shortcut(parent: jax.Array, steps: int) -> jax.Array:
 
 @partial(jax.jit, static_argnames=("n", "use_pallas"))
 def _forest_impl(src, dst, mask, n: int, init_labels=None,
-                 use_pallas: bool | None = None):
+                 use_pallas: bool = False):
     """Borůvka hooking. ``init_labels`` warm-starts from an existing
     partition (path-compressed component labels): the returned forest then
     contains only edges that merge ACROSS the initial components — the
@@ -98,7 +98,7 @@ def _forest_impl(src, dst, mask, n: int, init_labels=None,
     incremental). Rounds are data-dependent (convergence-tested while loop,
     bounded by log2(n)+2); the round count is returned for the measured
     roofline model. ``use_pallas`` selects the per-round edge-scan backend
-    (None = auto: fused Pallas kernel on TPU, jnp oracle elsewhere)."""
+    (``kernels.kernel_path``)."""
     E = src.shape[0]
     log_n = _ceil_log2(n)
     # Self-loops are never cross edges; masked slots never participate.
@@ -110,15 +110,15 @@ def _forest_impl(src, dst, mask, n: int, init_labels=None,
 
     def body(state):
         labels, forest, _, rounds = state
-        # fused round: tombstone mask + both label gathers + dual-endpoint
-        # segment-min in ONE streamed pass over the edge buffer
+        # one round: tombstone mask + both label gathers + dual-endpoint
+        # segment-min over the edge buffer
         with jax.named_scope("kernel/round/boruvka"):
             best = boruvka_round(src, dst, valid, labels, n,
                                  use_pallas=use_pallas)
         has = best < INF32
         e = jnp.where(has, best, 0)
         # O(n) gathers of the chosen edges' endpoint labels — the only
-        # post-reduction label reads (nothing E-sized after the fused pass)
+        # post-reduction label reads (nothing E-sized after the round)
         cu = labels[src[e]]
         cv = labels[dst[e]]
         comp = jnp.arange(n, dtype=INT)
@@ -144,7 +144,7 @@ def _forest_impl(src, dst, mask, n: int, init_labels=None,
     return forest, labels, rounds
 
 
-def spanning_forest(edges: EdgeList, use_pallas: bool | None = None):
+def spanning_forest(edges: EdgeList, use_pallas: bool = False):
     """Returns (forest_mask bool[E], labels int32[n]).
 
     ``forest_mask`` selects a spanning forest of the masked subgraph;
@@ -155,7 +155,7 @@ def spanning_forest(edges: EdgeList, use_pallas: bool | None = None):
 
 
 def spanning_forest_ex(edges: EdgeList, init_labels=None,
-                       use_pallas: bool | None = None):
+                       use_pallas: bool = False):
     """(forest_mask, labels, rounds_used); optional warm-start labels.
 
     With ``init_labels`` the forest spans only the *contraction* of the
@@ -167,7 +167,7 @@ def spanning_forest_ex(edges: EdgeList, init_labels=None,
                              init_labels=init_labels, use_pallas=use_pallas))
 
 
-def connected_components(edges: EdgeList, use_pallas: bool | None = None):
+def connected_components(edges: EdgeList, use_pallas: bool = False):
     """Component labels only (same hooking machinery)."""
     _, labels, _ = spanning_forest_ex(edges, use_pallas=use_pallas)
     return labels
@@ -176,7 +176,7 @@ def connected_components(edges: EdgeList, use_pallas: bool | None = None):
 # --------------------------------------------------------- scan-first search
 @partial(jax.jit, static_argnames=("n", "use_pallas"))
 def _sfs_impl(src, dst, mask, n: int, comp_labels,
-              use_pallas: bool | None = None):
+              use_pallas: bool = False):
     """Level-synchronous frontier hooking: a scan-first-search (BFS-layer)
     spanning forest, rooted at each component's minimum vertex id.
 
@@ -210,11 +210,11 @@ def _sfs_impl(src, dst, mask, n: int, comp_labels,
 
     def body(state):
         visited, level, parent, forest, frontier, _, rounds = state
-        # fused frontier round: candidate mask + both arc orientations +
-        # the lexicographic (parent id, edge slot) reduction in ONE
-        # streamed pass over the raw edge buffer. best_p = minimum-id
-        # frontier neighbor per newly reached vertex; best_e = minimum
-        # edge slot to that neighbor (ties on parallel edges).
+        # frontier round: candidate mask + both arc orientations + the
+        # lexicographic (parent id, edge slot) reduction over the raw edge
+        # buffer. best_p = minimum-id frontier neighbor per newly reached
+        # vertex; best_e = minimum edge slot to that neighbor (ties on
+        # parallel edges).
         with jax.named_scope("kernel/round/sfs"):
             best_p, best_e = frontier_round(src, dst, valid, frontier,
                                             visited, n,
@@ -234,7 +234,7 @@ def _sfs_impl(src, dst, mask, n: int, comp_labels,
     return forest, parent, level, root, rounds
 
 
-def scan_first_forest(edges: EdgeList, use_pallas: bool | None = None):
+def scan_first_forest(edges: EdgeList, use_pallas: bool = False):
     """Returns (forest_mask bool[E], parent int[n], level int[n]).
 
     The level-synchronous frontier-hooking primitive: a BFS-layer scan-first
@@ -247,7 +247,7 @@ def scan_first_forest(edges: EdgeList, use_pallas: bool | None = None):
     return f, p, lvl
 
 
-def scan_first_forest_ex(edges: EdgeList, use_pallas: bool | None = None):
+def scan_first_forest_ex(edges: EdgeList, use_pallas: bool = False):
     """(forest_mask, parent, level, root_labels, rounds_used).
 
     `root_labels[v]` is the component's canonical minimum vertex id — the

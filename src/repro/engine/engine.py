@@ -67,6 +67,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.connectivity.registry import get_analysis
 from repro.core.certs import (
@@ -1057,8 +1059,10 @@ class BridgeEngine:
                     psrc = np.pad(psrc, ((0, 0), (0, pad)))
                     pdst = np.pad(pdst, ((0, 0), (0, pad)))
                     pmask = np.pad(pmask, ((0, 0), (0, pad)))
-                args = (jnp.asarray(psrc), jnp.asarray(pdst),
-                        jnp.asarray(pmask))
+                # one [1, cap] row per machine, on that machine's device
+                rows = NamedSharding(self.mesh, P(self.machine_axes, None))
+                args = tuple(jax.device_put(x, rows)
+                             for x in (psrc, pdst, pmask))
                 kcap = None
                 if delete is not None:
                     # deletion keys are global: replicate to every machine,

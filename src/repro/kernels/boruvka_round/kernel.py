@@ -1,37 +1,40 @@
-"""Pallas TPU kernels: fused, multi-buffered connectivity-round reductions.
+"""Pallas TPU kernels: the connectivity-round reductions as one masked min.
 
-The Borůvka hooking loop (core/forest.py) is the hottest loop in the
-system: every certificate pass runs O(log V) rounds, and pre-fusion each
-round made THREE full trips over the edge buffer (build the cross mask +
-per-edge keys, segment-min over the src labels, segment-min over the dst
-labels). The paper's O(E/M + V·log M) cost model assumes that scan is
-bandwidth-bound, so trips are the currency. The kernels here do each
-round in ONE streamed pass:
+The Borůvka hooking loop (core/forest.py) runs O(log V) rounds per
+certificate pass, and each round reduces over the whole edge buffer: every
+component picks its minimum incident cross edge, over BOTH endpoints'
+labels. The kernels here do that reduction as one dense masked min over
+(edge tile x segment tile) blocks, the ``kernels/segment_min`` scheme:
 
-    grid = (num_segment_tiles,)                        # output-stationary
-    per tile j: acc[s] = INF
-      for each edge chunk i (quad-buffered HBM→VMEM DMA):
-        gather both endpoints' labels from the VMEM-resident label tile,
-        apply the tombstone/validity mask in-register,
-        acc[s] = min(acc, min over chunk of
-                     where(lu == s  OR  lv == s, edge_key, INF))
+    grid = (num_segment_tiles, num_edge_tiles)        # output-stationary
+    block (j, i): acc[s] = min(acc[s], min over edges t of
+                              where(lu[t] == s  OR  lv[t] == s, key[t], INF))
 
 Both endpoints' reductions happen in the SAME (edge × segment) compare on
-the VPU — the two back-to-back ``segment_min`` scatter passes collapse
-into one masked min, and the mask pass rides along for free. The edge
-chunks stream through ``N_BUFFERS`` VMEM slots with ``make_async_copy``:
-chunk i+N starts its DMA before chunk i's compute runs, so the next tile
-is in flight while the current one reduces (DESIGN.md §Kernels has the
-byte accounting: 9 bytes/edge/round streamed once vs 25 for the
-three-pass lax path).
+the VPU, so the two back-to-back ``segment_min`` passes of the oracle
+collapse into one masked min, and the tombstone/validity mask is applied
+in-register. The output tile stays resident in VMEM across the inner edge
+loop; every segment tile re-reads the edge arrays, so one round streams
+``⌈n / SEG_BLOCK⌉`` passes over them.
 
-``frontier_round`` fuses the scan-first-search round the same way, with
-two extras: both arc orientations are derived in VMEM from the raw edge
-buffer (the lax path materializes and re-reads 2E-slot ``us/ws/e2/v2``
-concatenations), and the reduction is LEXICOGRAPHIC on (parent id, edge
-slot) — two accumulators merged per chunk — so the parent choice and the
-tie-broken tree slot come out of one pass instead of two dependent
-segment-mins.
+What the kernels read: Mosaic lowers only 2-D gathers, so the per-edge
+gathers of the vertex arrays (``labels[src]``, ``labels[dst]``; the
+frontier and visited bits of both endpoints) run in XLA ahead of the
+``pallas_call``, and the kernels stream the gathered per-edge int32 arrays:
+
+  * ``boruvka_round``: ``lu = labels[src]``, ``lv = labels[dst]`` and the
+    mask. A self-loop has ``lu == lv``, so ``lu != lv`` is the whole cross
+    test;
+  * ``frontier_round``: ``src``, ``dst`` and a 2-bit candidate code per
+    edge (bit 0: the forward arc src→dst leaves the frontier for an
+    unvisited vertex; bit 1: the reverse arc). The reduction is
+    LEXICOGRAPHIC on (parent id, edge slot) — two accumulators merged per
+    block — so the parent choice and the tie-broken tree slot come out of
+    one pass instead of two dependent segment-mins.
+
+Block shapes: XLA tiles a 1-D int32 array in HBM as ``T(1024)``, and
+Mosaic refuses a 1-D block whose tiling differs, so edge and segment
+blocks are 1024 long (shared with ``kernels/segment_min``).
 
 Dtype contract: everything is ``datastructs.INT`` (int32) with INF32 as
 the empty/invalid sentinel; wrappers reject key spaces that could collide
@@ -44,20 +47,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.graph.datastructs import INF32, INT
-from repro.kernels.segment_min.kernel import check_key_space
-
-# VPU-aligned tiles (same shape economy as kernels/segment_min): edges per
-# streamed chunk x segment lanes per output tile.
-EDGE_BLOCK = 1024
-SEG_BLOCK = 512
-
-#: VMEM slots each streamed edge array rotates through (quad-buffered, the
-#: flash-attention benchmark exemplar's scheme): up to N_BUFFERS - 1 chunk
-#: DMAs in flight while one chunk computes.
-N_BUFFERS = 4
+from repro.kernels.segment_min.kernel import (
+    EDGE_BLOCK,
+    SEG_BLOCK,
+    check_key_space,
+)
 
 
 def _pad_edges(arrs, e: int):
@@ -68,177 +64,114 @@ def _pad_edges(arrs, e: int):
     return [jnp.pad(a, (0, e_pad - e)) for a in arrs], e_pad
 
 
-def _pad_nodes(arrs, n: int):
-    """Pad each [n] array to a multiple of SEG_BLOCK (zeros: in-range)."""
-    n_pad = pl.cdiv(n, SEG_BLOCK) * SEG_BLOCK
-    if n_pad == n:
-        return arrs, n_pad
-    return [jnp.pad(a, (0, n_pad - n)) for a in arrs], n_pad
+def _edge_pass(kernel, edge_arrays, num_segments: int, n_out: int,
+               interpret: bool):
+    """Run ``kernel`` over every (segment tile, edge tile) block of the
+    per-edge int32 ``edge_arrays``; ``n_out`` int32[num_segments] outputs,
+    each resident per segment tile across the inner edge loop."""
+    e = edge_arrays[0].shape[0]
+    check_key_space(e, num_segments)
+    arrs, e_pad = _pad_edges([a.astype(INT) for a in edge_arrays], e)
+    n_pad = pl.cdiv(num_segments, SEG_BLOCK) * SEG_BLOCK
+    edge_spec = pl.BlockSpec((EDGE_BLOCK,), lambda j, i: (i,))
+    seg_spec = pl.BlockSpec((SEG_BLOCK,), lambda j, i: (j,))
+    outs = pl.pallas_call(
+        kernel,
+        grid=(n_pad // SEG_BLOCK, e_pad // EDGE_BLOCK),
+        in_specs=[edge_spec] * len(arrs),
+        out_specs=[seg_spec] * n_out,
+        out_shape=[jax.ShapeDtypeStruct((n_pad,), INT)] * n_out,
+        interpret=interpret,
+    )(*arrs)
+    return tuple(o[:num_segments] for o in outs)
 
 
-def _stream_chunks(edge_refs, compute_chunk, e_pad: int):
-    """Run ``compute_chunk(i, bufs)`` over every EDGE_BLOCK chunk of the
-    HBM-resident ``edge_refs``, rotating each array through N_BUFFERS VMEM
-    slots with async DMA so chunk i+N streams in while chunk i reduces."""
-    num_chunks = e_pad // EDGE_BLOCK
-    n_arrays = len(edge_refs)
-
-    def body(*scoped):
-        bufs, sem = scoped[:n_arrays], scoped[n_arrays]
-
-        def dma(slot, i, k):
-            return pltpu.make_async_copy(
-                edge_refs[k].at[pl.ds(i * EDGE_BLOCK, EDGE_BLOCK)],
-                bufs[k].at[slot], sem.at[slot, k])
-
-        for w in range(min(N_BUFFERS, num_chunks)):  # warm-up fills
-            for k in range(n_arrays):
-                dma(w, w, k).start()
-
-        def loop(i, carry):
-            slot = i % N_BUFFERS
-            for k in range(n_arrays):
-                dma(slot, i, k).wait()
-            compute_chunk(i, [b[slot] for b in bufs])
-
-            @pl.when(i + N_BUFFERS < num_chunks)
-            def _():  # reuse the slot for the chunk N_BUFFERS ahead
-                for k in range(n_arrays):
-                    dma(slot, i + N_BUFFERS, k).start()
-            return carry
-
-        jax.lax.fori_loop(0, num_chunks, loop, 0)
-
-    pl.run_scoped(
-        body,
-        *[pltpu.VMEM((N_BUFFERS, EDGE_BLOCK), INT) for _ in range(n_arrays)],
-        pltpu.SemaphoreType.DMA((N_BUFFERS, n_arrays)),
-    )
+def _tile_ids():
+    """(segment ids [1, SEG_BLOCK], edge slots [EDGE_BLOCK, 1]) of the
+    current (segment tile, edge tile) block."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    seg_ids = j * SEG_BLOCK + jax.lax.broadcasted_iota(INT, (1, SEG_BLOCK), 1)
+    eidx = i * EDGE_BLOCK + jax.lax.broadcasted_iota(INT, (EDGE_BLOCK, 1), 0)
+    return seg_ids, eidx
 
 
-def _boruvka_round_kernel(labels_ref, src_ref, dst_ref, mask_ref, out_ref):
-    j = pl.program_id(0)
-    seg_ids = j * SEG_BLOCK + jax.lax.broadcasted_iota(
-        INT, (1, SEG_BLOCK), 1)
-    labels = labels_ref[...]
-    out_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
+def _boruvka_round_kernel(lu_ref, lv_ref, mask_ref, out_ref):
+    seg_ids, eidx = _tile_ids()
+    # edges down the sublanes: [EDGE_BLOCK, 1] columns (Mosaic reshapes
+    # 1-D int32 vectors, not 1-D masks, so the masks are built as columns)
+    lu, lv, msk = (r[...][:, None] for r in (lu_ref, lv_ref, mask_ref))
+    # tombstone/validity mask + cross test, in-register (self-loops have
+    # lu == lv)
+    key = jnp.where((msk != 0) & (lu != lv), eidx, INF32)
+    hit = (lu == seg_ids) | (lv == seg_ids)
+    partial = jnp.min(jnp.where(hit, key, INF32), axis=0)
 
-    def compute_chunk(i, bufs):
-        src, dst, msk = bufs
-        lu = labels[src]
-        lv = labels[dst]
-        # tombstone/validity mask + self-loop + cross test, in-register
-        cross = (msk != 0) & (src != dst) & (lu != lv)
-        eidx = i * EDGE_BLOCK + jax.lax.broadcasted_iota(
-            INT, (EDGE_BLOCK, 1), 0)
-        key = jnp.where(cross[:, None], eidx, INF32)  # [EDGE_BLOCK, 1]
-        hit = (lu[:, None] == seg_ids) | (lv[:, None] == seg_ids)
-        partial = jnp.min(jnp.where(hit, key, INF32), axis=0)
-        out_ref[...] = jnp.minimum(out_ref[...], partial)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        out_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
 
-    _stream_chunks([src_ref, dst_ref, mask_ref], compute_chunk,
-                   src_ref.shape[0])
+    out_ref[...] = jnp.minimum(out_ref[...], partial)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
 def boruvka_round_pallas(src, dst, mask, labels, num_segments: int,
                          interpret: bool = False):
-    """Fused Borůvka round: see ``ref.boruvka_round_ref`` for the contract.
+    """Borůvka round: see ``ref.boruvka_round_ref`` for the contract.
 
-    One streamed pass over (src, dst, mask); labels tile VMEM-resident;
-    output accumulator VMEM-resident per segment tile.
+    XLA gathers both endpoints' labels; the kernel streams (lu, lv, mask)
+    and reduces both endpoints in one masked min per block.
     """
-    e = src.shape[0]
-    check_key_space(e, num_segments)
-    (src, dst, msk), e_pad = _pad_edges(
-        [src.astype(INT), dst.astype(INT), mask.astype(INT)], e)
-    (labels,), n_pad = _pad_nodes([labels.astype(INT)], num_segments)
-    out = pl.pallas_call(
-        _boruvka_round_kernel,
-        grid=(n_pad // SEG_BLOCK,),
-        in_specs=[
-            pl.BlockSpec((n_pad,), lambda j: (0,)),  # labels: whole, VMEM
-            pl.BlockSpec(memory_space=pltpu.ANY),    # edges stay in HBM,
-            pl.BlockSpec(memory_space=pltpu.ANY),    # DMA-streamed by the
-            pl.BlockSpec(memory_space=pltpu.ANY),    # kernel itself
-        ],
-        out_specs=pl.BlockSpec((SEG_BLOCK,), lambda j: (j,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), INT),
-        interpret=interpret,
-    )(labels, src, dst, msk)
-    return out[:num_segments]
+    labels = labels.astype(INT)
+    (out,) = _edge_pass(_boruvka_round_kernel,
+                        [labels[src], labels[dst], mask], num_segments, 1,
+                        interpret)
+    return out
 
 
-def _frontier_round_kernel(frontier_ref, visited_ref, src_ref, dst_ref,
-                           mask_ref, p_ref, e_ref):
-    j = pl.program_id(0)
-    seg_ids = j * SEG_BLOCK + jax.lax.broadcasted_iota(
-        INT, (1, SEG_BLOCK), 1)
-    frontier = frontier_ref[...]
-    visited = visited_ref[...]
-    p_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
-    e_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
+def _frontier_round_kernel(src_ref, dst_ref, cand_ref, p_ref, e_ref):
+    seg_ids, eidx = _tile_ids()
+    src, dst, cand = (r[...][:, None] for r in (src_ref, dst_ref, cand_ref))
+    cand_f = (cand & 1) != 0  # arc src -> dst reaches dst
+    cand_r = (cand & 2) != 0  # arc dst -> src reaches src
+    hit_f = cand_f & (dst == seg_ids)
+    hit_r = cand_r & (src == seg_ids)
+    p_blk = jnp.minimum(
+        jnp.min(jnp.where(hit_f, src, INF32), axis=0),
+        jnp.min(jnp.where(hit_r, dst, INF32), axis=0))
+    sel_f = hit_f & (src == p_blk[None, :])
+    sel_r = hit_r & (dst == p_blk[None, :])
+    e_blk = jnp.minimum(
+        jnp.min(jnp.where(sel_f, eidx, INF32), axis=0),
+        jnp.min(jnp.where(sel_r, eidx, INF32), axis=0))
 
-    def compute_chunk(i, bufs):
-        src, dst, msk = bufs
-        valid = (msk != 0) & (src != dst)
-        # both arc orientations derived here, in VMEM — the raw edge buffer
-        # is streamed once, not a 2E concatenation twice
-        cand_f = valid & (frontier[src] != 0) & (visited[dst] == 0)
-        cand_r = valid & (frontier[dst] != 0) & (visited[src] == 0)
-        hit_f = cand_f[:, None] & (dst[:, None] == seg_ids)
-        hit_r = cand_r[:, None] & (src[:, None] == seg_ids)
-        p_chunk = jnp.minimum(
-            jnp.min(jnp.where(hit_f, src[:, None], INF32), axis=0),
-            jnp.min(jnp.where(hit_r, dst[:, None], INF32), axis=0))
-        eidx = i * EDGE_BLOCK + jax.lax.broadcasted_iota(
-            INT, (EDGE_BLOCK, 1), 0)
-        sel_f = hit_f & (src[:, None] == p_chunk[None, :])
-        sel_r = hit_r & (dst[:, None] == p_chunk[None, :])
-        e_chunk = jnp.minimum(
-            jnp.min(jnp.where(sel_f, eidx, INF32), axis=0),
-            jnp.min(jnp.where(sel_r, eidx, INF32), axis=0))
-        # lexicographic merge with the accumulators: parent id first, then
-        # minimum edge slot among edges to that parent
-        acc_p, acc_e = p_ref[...], e_ref[...]
-        e_ref[...] = jnp.where(
-            p_chunk < acc_p, e_chunk,
-            jnp.where(p_chunk == acc_p, jnp.minimum(acc_e, e_chunk), acc_e))
-        p_ref[...] = jnp.minimum(acc_p, p_chunk)
+    @pl.when(pl.program_id(1) == 0)
+    def _init():
+        p_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
+        e_ref[...] = jnp.full((SEG_BLOCK,), INF32, INT)
 
-    _stream_chunks([src_ref, dst_ref, mask_ref], compute_chunk,
-                   src_ref.shape[0])
+    # lexicographic merge with the accumulators: parent id first, then
+    # minimum edge slot among edges to that parent
+    acc_p, acc_e = p_ref[...], e_ref[...]
+    e_ref[...] = jnp.where(
+        p_blk < acc_p, e_blk,
+        jnp.where(p_blk == acc_p, jnp.minimum(acc_e, e_blk), acc_e))
+    p_ref[...] = jnp.minimum(acc_p, p_blk)
 
 
 @functools.partial(jax.jit, static_argnames=("num_segments", "interpret"))
 def frontier_round_pallas(src, dst, mask, frontier, visited,
                           num_segments: int, interpret: bool = False):
-    """Fused scan-first-search round: contract in ``ref.frontier_round_ref``.
+    """Scan-first-search round: contract in ``ref.frontier_round_ref``.
 
-    Returns ``(best_p, best_e)`` int32[num_segments]; one streamed pass over
-    the raw edge buffer, frontier/visited tiles VMEM-resident.
+    Returns ``(best_p, best_e)`` int32[num_segments]. XLA gathers the
+    frontier/visited bits of both endpoints into a per-edge candidate code;
+    the kernel streams (src, dst, code) and reduces both arc orientations.
     """
-    e = src.shape[0]
-    check_key_space(e, num_segments)
-    (src, dst, msk), e_pad = _pad_edges(
-        [src.astype(INT), dst.astype(INT), mask.astype(INT)], e)
-    (frontier, visited), n_pad = _pad_nodes(
-        [frontier.astype(INT), visited.astype(INT)], num_segments)
-    node_spec = pl.BlockSpec((n_pad,), lambda j: (0,))
-    seg_spec = pl.BlockSpec((SEG_BLOCK,), lambda j: (j,))
-    best_p, best_e = pl.pallas_call(
-        _frontier_round_kernel,
-        grid=(n_pad // SEG_BLOCK,),
-        in_specs=[
-            node_spec,                               # frontier: whole, VMEM
-            node_spec,                               # visited: whole, VMEM
-            pl.BlockSpec(memory_space=pltpu.ANY),    # edges stay in HBM,
-            pl.BlockSpec(memory_space=pltpu.ANY),    # DMA-streamed by the
-            pl.BlockSpec(memory_space=pltpu.ANY),    # kernel itself
-        ],
-        out_specs=(seg_spec, seg_spec),
-        out_shape=(jax.ShapeDtypeStruct((n_pad,), INT),
-                   jax.ShapeDtypeStruct((n_pad,), INT)),
-        interpret=interpret,
-    )(frontier, visited, src, dst, msk)
-    return best_p[:num_segments], best_e[:num_segments]
+    frontier = frontier.astype(bool)
+    visited = visited.astype(bool)
+    valid = mask.astype(bool) & (src != dst)
+    fwd = valid & frontier[src] & ~visited[dst]
+    rev = valid & frontier[dst] & ~visited[src]
+    cand = fwd.astype(INT) | (rev.astype(INT) << 1)
+    return _edge_pass(_frontier_round_kernel, [src, dst, cand],
+                      num_segments, 2, interpret)

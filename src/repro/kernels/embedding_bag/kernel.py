@@ -76,7 +76,7 @@ def embedding_bag_pallas(table, indices, mask=None, mode: str = "sum",
         in_specs=[
             pl.BlockSpec((B_TILE, l), lambda i: (i, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((B_TILE, l), lambda i: (i, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # table stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
         ],
         out_specs=pl.BlockSpec((B_TILE, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b_pad, d), jnp.float32),

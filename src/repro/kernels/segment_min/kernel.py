@@ -12,10 +12,12 @@ masked min over (edge-tile x segment-tile) blocks:
                    where(ids[t] == seg_base_j + s, keys[t], INF)
 
 The output block j stays resident in VMEM across the inner edge-tile loop
-(revisited-accumulator pattern), so HBM traffic is E·(keys+ids) reads + N
-writes. Compare work E·N masked ops vs a sort-based reduce's E·log E shuffle
-passes: for the merge phases (E <= 4(n-1)) the dense form wins on the VPU's
-8x128 lanes; DESIGN.md §Perf quantifies the crossover.
+(revisited-accumulator pattern), so HBM traffic is E·(keys+ids) reads per
+segment tile + N writes, and the work is E·N masked ops.
+
+Block shapes: XLA tiles a 1-D int32 array in HBM as ``T(1024)``, and Mosaic
+refuses a 1-D block whose tiling differs, so both the edge and the segment
+blocks are 1024 long.
 """
 from __future__ import annotations
 
@@ -27,9 +29,9 @@ from jax.experimental import pallas as pl
 
 from repro.graph.datastructs import INF32, INT
 
-# VPU-aligned tiles: edges per block x segments per block
+# 1-D blocks: multiples of XLA's T(1024) tiling of an int32 vector
 EDGE_BLOCK = 1024
-SEG_BLOCK = 512
+SEG_BLOCK = 1024
 
 
 def check_key_space(e: int, num_segments: int, *, edge_block: int = EDGE_BLOCK,
@@ -87,7 +89,7 @@ def segment_min_pallas(
     check_key_space(e, num_segments)
     keys = keys.astype(INT)
     ids = ids.astype(INT)
-    e_pad = pl.cdiv(e, EDGE_BLOCK) * EDGE_BLOCK
+    e_pad = pl.cdiv(max(e, 1), EDGE_BLOCK) * EDGE_BLOCK
     n_pad = pl.cdiv(num_segments, SEG_BLOCK) * SEG_BLOCK
     if e_pad != e:
         keys = jnp.pad(keys, (0, e_pad - e), constant_values=INF32)

@@ -1,25 +1,16 @@
-"""Public segment_min op: Pallas kernel on TPU, interpret-mode kernel or the
-jnp oracle elsewhere."""
+"""Public segment_min op: the XLA scatter-min by default, the Pallas kernel
+when a caller sets ``use_pallas`` (see ``repro.kernels.kernel_path``)."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import kernel_path
 from repro.kernels.segment_min.kernel import segment_min_pallas
 from repro.kernels.segment_min.ref import segment_min_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def segment_min(keys, ids, num_segments: int, use_pallas: bool | None = None):
-    """min(keys) per segment id; empty segments -> INF32.
-
-    use_pallas: force kernel (interpret-mode off-TPU); default: kernel on TPU,
-    jnp scatter-min elsewhere (faster than interpret mode on CPU).
-    """
-    if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas:
-        return segment_min_pallas(keys, ids, num_segments, interpret=not _on_tpu())
-    return segment_min_ref(keys, ids, num_segments)
+def segment_min(keys, ids, num_segments: int, use_pallas: bool = False):
+    """min(keys) per segment id; empty segments -> INF32."""
+    path = kernel_path(use_pallas)
+    if path == "oracle":
+        return segment_min_ref(keys, ids, num_segments)
+    return segment_min_pallas(keys, ids, num_segments,
+                              interpret=path == "interpret")

@@ -14,8 +14,8 @@ dashboards can track the substrate matrix. ``--json`` writes the per-kind
 rates plus the engine's ``snapshot()`` rollup (programs/hits/misses/
 traces/hit_rate — one dict, never re-derived here); each kind's row
 also carries ``kernel_path`` — the backend (``pallas`` | ``interpret`` |
-``oracle``) the certificate's fused per-round edge scan resolved to for
-the served requests (DESIGN.md §Kernels).
+``oracle``) the certificate's per-round edge scan ran on for the served
+requests (DESIGN.md §Kernels).
 
 Every request is also timed into fixed-bucket latency HISTOGRAMS — per
 kind and per served certificate, one histogram per serving phase — and
@@ -87,6 +87,7 @@ from repro.engine import BridgeEngine, BridgeScheduler
 from repro.graph import generators as gen
 from repro.graph.datastructs import admission_capacity
 from repro.kernels.boruvka_round import kernel_path
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import MetricsRegistry, profiler_trace
 
 #: CLI spellings: canonical kinds, with '-' aliases for the shell
@@ -524,11 +525,12 @@ def serve_multitenant(engine: BridgeEngine, kinds, args,
                 continue
             sched.drain()
         for tk, ev in arrivals:
+            got = tk.result()  # a failed ticket raises its error here
             observe("sched", ev["tenant"], tk.t_done - start - ev["t"])
             if args.verify and ev is events[0] and ev["op"] == "analyze":
                 s, d, nq = ev["graph"]
                 want = get_analysis(ev["kind"]).host_fn(s, d, nq)
-                assert _same(ev["kind"], tk.result(), want), "mt sched mismatch"
+                assert _same(ev["kind"], got, want), "mt sched mismatch"
         serve_scheduler.sched = sched
 
     seq = run_phase("seq", serve_sequential)
@@ -780,6 +782,7 @@ def main(argv=None):
                 0 <= args.kill_machine < args.machines):
             ap.error("--kill-machine must name a fleet machine")
 
+    use_compile_cache()
     engine = BridgeEngine(certificate=args.certificate)
     metrics = MetricsRegistry()
     tracer = obs.enable_tracing() if args.trace_out else None

@@ -1,45 +1,5 @@
-import os
-
 import networkx as nx
 import numpy as np
-import pytest
-
-
-def modern_sharding_jax() -> bool:
-    """True when this jax build has the modern sharding surface the
-    models/launch/distributed code paths use. This container's jax predates
-    it (ROADMAP: distributed shard_map paths need a newer jax), so tests of
-    those paths carry ``requires_modern_sharding`` and tier-1 collects green
-    instead of masking real regressions behind known version noise.
-
-    ``REPRO_FORCE_MODERN_SHARDING=1`` overrides the detection and force-runs
-    the gated tests regardless — the nightly CI job sets it on latest
-    ``jax[cpu]`` so those ~25 distributed paths get real coverage (a truly
-    old jax then fails them loudly instead of skipping, which is the
-    point)."""
-    if os.environ.get("REPRO_FORCE_MODERN_SHARDING", "").lower() in (
-            "1", "true", "yes"):
-        return True
-    import jax
-    import jax.sharding
-
-    return all([
-        hasattr(jax, "shard_map"),
-        hasattr(jax, "set_mesh"),
-        hasattr(jax.sharding, "AxisType"),
-        hasattr(jax.sharding, "get_abstract_mesh"),
-    ])
-
-
-#: version gate for tests that exercise jax.shard_map / jax.set_mesh /
-#: AxisType / get_abstract_mesh — skip (not run-to-failure) so the known
-#: version noise costs no CI time; on a modern jax the gate is inert and
-#: the tests run for real.
-requires_modern_sharding = pytest.mark.skipif(
-    not modern_sharding_jax(),
-    reason="this jax build lacks the modern sharding API "
-           "(jax.shard_map / jax.set_mesh / AxisType / get_abstract_mesh)",
-)
 
 # Shape buckets: property tests draw (n, edge-capacity) from this fixed set so
 # jit caches hit instead of recompiling per hypothesis example (1-core box).

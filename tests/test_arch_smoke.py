@@ -20,7 +20,6 @@ from repro.training import (
     make_recsys_steps,
 )
 
-from helpers import requires_modern_sharding
 
 PAR = Parallelism.none()
 LM_ARCHS = ["qwen3_0_6b", "stablelm_12b", "qwen3_14b", "dbrx_132b",
@@ -33,7 +32,6 @@ def _finite(tree):
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)
-@requires_modern_sharding
 def test_lm_smoke_train_step(arch):
     cfg = get(arch).smoke_config
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
@@ -54,7 +52,6 @@ def test_lm_smoke_train_step(arch):
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)
-@requires_modern_sharding
 def test_lm_smoke_decode(arch):
     cfg = get(arch).smoke_config
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
@@ -116,7 +113,6 @@ def test_graphsage_smoke_sampled():
     assert np.isfinite(float(metrics["loss"]))
 
 
-@requires_modern_sharding
 def test_sasrec_smoke_all_modes():
     cfg = get("sasrec").smoke_config
     key = jax.random.PRNGKey(0)
@@ -142,7 +138,6 @@ def test_sasrec_smoke_all_modes():
     assert rs.shape == (1, 64) and np.isfinite(np.asarray(rs)).all()
 
 
-@requires_modern_sharding
 def test_sasrec_bulk_topk_matches_full_scores():
     """Shard-local top-k + merge must be EXACTLY the full-table top-k
     (the distributed-serving optimization cannot change results)."""
@@ -178,7 +173,6 @@ def test_registry_complete():
         assert spec.smoke_config is not None, a
 
 
-@requires_modern_sharding
 def test_head_padding_is_exact():
     """TP head padding (e.g. 40->48 heads) must be mathematically invisible:
     embedding the real heads of an UNPADDED model into the padded layout

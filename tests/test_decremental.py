@@ -27,7 +27,6 @@ from repro.graph import generators as gen
 from repro.graph.datastructs import EdgeList
 
 from _hyp import given, st
-from helpers import requires_modern_sharding
 
 N, E0 = 48, 150          # n_bucket 64, full-buffer bucket 256
 DELTA = 12               # insert/delete batch sizes land in key bucket 16
@@ -286,7 +285,6 @@ def test_simulate_churn_host_matches_recompute(certificate, kind, schedule):
     assert len(certs[0].to_numpy()[0]) <= len(base[0].to_numpy()[0])
 
 
-@requires_modern_sharding
 def test_distributed_deletion_end_to_end_shard_map():
     """Engine analyze(delete=...) on a mesh == single-device analyze with
     the same deletions, every kind (subprocess with 4 forced host devs)."""

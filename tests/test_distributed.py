@@ -10,7 +10,6 @@ from pathlib import Path
 
 import pytest
 
-from helpers import requires_modern_sharding
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -30,7 +29,6 @@ def run_with_devices(code: str, n_devices: int = 8) -> subprocess.CompletedProce
 
 @pytest.mark.parametrize("schedule", ["paper", "xor"])
 @pytest.mark.parametrize("final", ["host", "device"])
-@requires_modern_sharding
 def test_distributed_matches_oracle(schedule, final):
     r = run_with_devices(f"""
         import jax
@@ -52,7 +50,6 @@ def test_distributed_matches_oracle(schedule, final):
 
 
 @pytest.mark.parametrize("schedule", ["paper", "xor"])
-@requires_modern_sharding
 def test_distributed_incremental_merge_matches_oracle(schedule):
     """Beyond-paper warm-start merge: same bridges as the oracle end-to-end."""
     r = run_with_devices(f"""
@@ -75,7 +72,6 @@ def test_distributed_incremental_merge_matches_oracle(schedule):
     assert "OK" in r.stdout
 
 
-@requires_modern_sharding
 def test_retrieval_score_then_combine_matches_gather():
     """Score-then-combine retrieval (shard_map over the row-sharded table)
     must equal the plain gathered-embedding dot."""
@@ -106,7 +102,6 @@ def test_retrieval_score_then_combine_matches_gather():
     assert "OK" in r.stdout
 
 
-@requires_modern_sharding
 def test_hierarchical_2d_mesh():
     r = run_with_devices("""
         import jax
@@ -126,7 +121,6 @@ def test_hierarchical_2d_mesh():
     assert "OK" in r.stdout
 
 
-@requires_modern_sharding
 def test_xor_schedule_gives_answer_on_every_machine():
     """Beyond-paper property: after recursive doubling, *any* machine can
     serve the result (fault-tolerance redundancy)."""

@@ -9,7 +9,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from helpers import requires_modern_sharding
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -32,7 +31,6 @@ def _final_loss(stdout: str) -> float:
 
 
 @pytest.mark.slow
-@requires_modern_sharding
 def test_crash_restart_reaches_same_state(tmp_path):
     """Run A: uninterrupted 30 steps. Run B: killed at step 17, restarted.
     Both must land on the identical final loss (bitwise-deterministic data +
@@ -52,7 +50,6 @@ def test_crash_restart_reaches_same_state(tmp_path):
     assert loss_a == pytest.approx(loss_b, rel=1e-5)
 
 
-@requires_modern_sharding
 def test_elastic_reshard_across_device_counts(tmp_path):
     """Checkpoint written under an 8-device mesh restores onto a 4-device
     mesh (elastic scale-down) with identical values."""

@@ -9,7 +9,6 @@ import sys
 import textwrap
 from pathlib import Path
 
-from helpers import requires_modern_sharding
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -24,7 +23,6 @@ def run_with_devices(code: str, n_devices: int = 8) -> subprocess.CompletedProce
     )
 
 
-@requires_modern_sharding
 def test_pp_loss_matches_non_pp():
     r = run_with_devices("""
         import jax, jax.numpy as jnp, numpy as np

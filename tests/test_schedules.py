@@ -24,7 +24,7 @@ from repro.engine import BridgeEngine, make_analysis_fn
 from repro.graph import generators as gen
 from repro.graph.datastructs import EdgeList
 
-from helpers import nx_bridges, requires_modern_sharding
+from helpers import nx_bridges
 
 M, GRID = 8, (2, 4)
 
@@ -99,7 +99,6 @@ def test_distributed_kind_matches_single_device_all_schedules(kind):
             assert host_got == want, (kind, schedule)
 
 
-@requires_modern_sharding
 def test_three_schedules_end_to_end_shard_map():
     """Full collective pipeline (subprocess with 8 forced host devices)."""
     import os
