@@ -142,16 +142,4 @@ def run(out, smoke: bool = False):
         f"programs={info['programs']} misses={info['misses']} "
         f"traces={info['traces']}"))
 
-    # trace mode only: one host-dispatched Borůvka + SFS pass over the base
-    # graph, emitting the measured kernel/forest spans with their synthetic
-    # kernel/round children (analytic bytes attached) — the kernel-round
-    # slice of the fig6 stage rollup. Guarded on the tracer so the
-    # non-trace record set (and BENCH_baseline.json) is untouched.
-    if get_tracer().enabled:
-        from repro.core.forest import scan_first_forest_ex, spanning_forest_ex
-        from repro.graph.datastructs import EdgeList
-
-        el = EdgeList.from_arrays(s0, d0, n0)
-        spanning_forest_ex(el)
-        scan_first_forest_ex(el)
     return out

@@ -47,17 +47,29 @@ def tour_state(src, dst, mask, n: int) -> dict:
       bridge    bool[C]  tree edge whose child subtree no non-tree edge
                          escapes — the paper's bridge criterion
     """
-    edges = EdgeList(src, dst, mask, n)
-    tree_mask, labels = spanning_forest(edges)
-    nt_mask = mask & ~tree_mask & (src != dst)
+    # three phases under their own named scopes, each outside the nested
+    # jit it wraps (DESIGN.md §Observability): the forest, the tour, and
+    # everything that derives low/high values from the tour
+    with jax.named_scope("tour/forest"):
+        tree_mask, labels = spanning_forest(EdgeList(src, dst, mask, n))
 
-    tour = euler_tour(
-        jnp.where(tree_mask, src, 0),
-        jnp.where(tree_mask, dst, 0),
-        tree_mask,
-        labels,
-        n,
-    )
+    with jax.named_scope("tour/euler"):
+        tour = euler_tour(
+            jnp.where(tree_mask, src, 0),
+            jnp.where(tree_mask, dst, 0),
+            tree_mask,
+            labels,
+            n,
+        )
+
+    with jax.named_scope("tour/lowhigh"):
+        return _low_high(src, dst, mask, n, tree_mask, labels, tour)
+
+
+def _low_high(src, dst, mask, n: int, tree_mask, labels, tour) -> dict:
+    """The ``tour/lowhigh`` phase of ``tour_state``: non-tree reach, its
+    subtree range reductions, the bridge test, orientation and ``vhi``."""
+    nt_mask = mask & ~tree_mask & (src != dst)
     gpos, disc = tour["gpos"], tour["disc"]
 
     # non-tree reach per vertex (include own discovery position)

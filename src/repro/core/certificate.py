@@ -12,11 +12,7 @@ from __future__ import annotations
 
 import jax
 
-from repro.core.forest import (
-    scan_first_forest_ex,
-    spanning_forest,
-    spanning_forest_ex,
-)
+from repro.core.forest import scan_first_forest_ex, spanning_forest_ex
 from repro.graph.datastructs import INT, EdgeList, compact_edges, concat_edges
 
 
@@ -24,19 +20,10 @@ def certificate_capacity(n_nodes: int) -> int:
     return max(2 * (n_nodes - 1), 1)
 
 
-def certificate_mask(edges: EdgeList):
-    """bool[E] selecting F1 ∪ F2 inside the input buffer (no compaction)."""
-    f1, _ = spanning_forest(edges)
-    rest = EdgeList(edges.src, edges.dst, edges.mask & ~f1, edges.n_nodes)
-    f2, _ = spanning_forest(rest)
-    return f1 | f2, f1
-
-
 def sparse_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList:
     """Compute the certificate and compact it into a 2(n−1)-slot buffer."""
-    cap = certificate_capacity(edges.n_nodes) if capacity is None else capacity
-    cert, _ = certificate_mask(edges)
-    return compact_edges(edges, cap, keep=cert)
+    cert, _, _, _ = sparse_certificate_ex(edges, capacity=capacity)
+    return cert
 
 
 def merge_certificates(a: EdgeList, b: EdgeList) -> EdgeList:
@@ -50,12 +37,17 @@ def sparse_certificate_ex(edges: EdgeList, capacity: int | None = None):
 
     The labels seed the INCREMENTAL merge below: they are the state that
     lets later phases skip re-certifying edges they already know about.
+    The passes run under the phase scopes ``cert/forest1``,
+    ``cert/forest2`` and ``cert/compact`` (DESIGN.md §Observability).
     """
     cap = certificate_capacity(edges.n_nodes) if capacity is None else capacity
-    f1, lab1, r1 = spanning_forest_ex(edges)
-    rest = EdgeList(edges.src, edges.dst, edges.mask & ~f1, edges.n_nodes)
-    f2, lab2, r2 = spanning_forest_ex(rest)
-    cert = compact_edges(edges, cap, keep=f1 | f2)
+    with jax.named_scope("cert/forest1"):
+        f1, lab1, r1 = spanning_forest_ex(edges)
+    with jax.named_scope("cert/forest2"):
+        rest = EdgeList(edges.src, edges.dst, edges.mask & ~f1, edges.n_nodes)
+        f2, lab2, r2 = spanning_forest_ex(rest)
+    with jax.named_scope("cert/compact"):
+        cert = compact_edges(edges, cap, keep=f1 | f2)
     return cert, lab1, lab2, (r1, r2)
 
 
@@ -195,10 +187,13 @@ def merge_certificates_incremental(own: EdgeList, f1_labels, f2_labels,
     Returns (merged_cert, f1_labels', f2_labels', (rounds_f1, rounds_f2)).
     """
     cap = certificate_capacity(own.n_nodes)
-    f1d, f1_labels, r1 = spanning_forest_ex(recv, init_labels=f1_labels)
-    rest = EdgeList(recv.src, recv.dst, recv.mask & ~f1d, recv.n_nodes)
-    f2d, f2_labels, r2 = spanning_forest_ex(rest, init_labels=f2_labels)
-    keep_recv = EdgeList(recv.src, recv.dst, recv.mask & (f1d | f2d),
-                         recv.n_nodes)
-    cert = compact_edges(concat_edges(own, keep_recv), cap)
+    with jax.named_scope("cert/forest1"):
+        f1d, f1_labels, r1 = spanning_forest_ex(recv, init_labels=f1_labels)
+    with jax.named_scope("cert/forest2"):
+        rest = EdgeList(recv.src, recv.dst, recv.mask & ~f1d, recv.n_nodes)
+        f2d, f2_labels, r2 = spanning_forest_ex(rest, init_labels=f2_labels)
+    with jax.named_scope("cert/compact"):
+        keep_recv = EdgeList(recv.src, recv.dst, recv.mask & (f1d | f2d),
+                             recv.n_nodes)
+        cert = compact_edges(concat_edges(own, keep_recv), cap)
     return cert, f1_labels, f2_labels, (r1, r2)

@@ -12,8 +12,14 @@ with zero engine edits (proven by ``hybrid``, which no engine file names).
 Each ``Certificate`` declares (DESIGN.md §Certificate registry):
 
 * ``build`` — the pure traced builder: ``(EdgeList, capacity=...) ->``
-  certificate pair in a fixed 2(n−1)-slot buffer. Used by the one-shot
-  pipelines and by the recertify merge phases (union-then-rebuild).
+  certificate pair in a fixed 2(n−1)-slot buffer. Used by the recertify
+  merge phases (union-then-rebuild).
+* ``build_ex`` — the same builder with its forests' round counts:
+  ``(EdgeList, capacity=...) -> (certificate, int32[2])``, the rounds up
+  to and including the first forest and those of the second. It is the
+  type's ``*_ex`` builder viewed through ``counted`` (no second path).
+  Used by the one-shot pipelines, which return the counts beside the
+  answer (``certificate/rounds``, DESIGN.md §Observability).
 * ``load_state`` — ``(EdgeList, capacity) -> state``: the live-serving
   state, a flat tuple whose FIRST THREE leaves are the pair's
   ``(src, dst, mask)`` buffers and whose remaining leaves are whatever
@@ -46,14 +52,18 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
+import jax.numpy as jnp
+
 from repro.core.certificate import (
     hybrid_certificate,
+    hybrid_certificate_ex,
     merge_certificates_incremental,
     sfs_certificate,
+    sfs_certificate_ex,
     sparse_certificate,
     sparse_certificate_ex,
 )
-from repro.graph.datastructs import EdgeList, concat_edges
+from repro.graph.datastructs import INT, EdgeList, concat_edges
 
 #: the structure tokens ``preserves`` may declare
 PRESERVABLE = frozenset({"lambda2", "kappa2"})
@@ -64,6 +74,7 @@ class Certificate:
     """Descriptor for one sparse-certificate type (see module docstring).
 
     build      : (EdgeList, capacity=...) -> EdgeList
+    build_ex   : (EdgeList, capacity=...) -> (EdgeList, int32[2] rounds)
     load_state : (EdgeList, capacity) -> (src, dst, mask, *aux)
     fold_state : ((src, dst, mask, *aux), recv EdgeList, capacity) -> state
     """
@@ -72,6 +83,7 @@ class Certificate:
     summary: str
     preserves: frozenset
     build: Callable
+    build_ex: Callable
     load_state: Callable
     fold_state: Callable
     lazy: bool = False
@@ -149,6 +161,20 @@ def primary_certificate() -> str:
 
 
 # -------------------------------------------------------------- state glue
+def counted(build_ex: Callable) -> Callable:
+    """``build_ex`` view of an ``*_ex`` builder, whose first output is the
+    certificate and whose last is its passes' round counts: the counts
+    fold into ``int32[2]`` — every pass before the second forest, then the
+    second forest (the hybrid's chain contraction joins its first)."""
+
+    def build(edges: EdgeList, capacity: int | None = None):
+        out = build_ex(edges, capacity=capacity)
+        *first, second = out[-1]
+        return out[0], jnp.stack([sum(first), second]).astype(INT)
+
+    return build
+
+
 def _pair_state(cert: EdgeList) -> tuple:
     return cert.src, cert.dst, cert.mask
 
@@ -195,6 +221,7 @@ register_certificate(Certificate(
             "preserves min(λ, 2); warm-start labels make deltas cheap",
     preserves=frozenset({"lambda2"}),
     build=sparse_certificate,
+    build_ex=counted(sparse_certificate_ex),
     load_state=_warm_load,
     fold_state=_warm_fold,
     lazy=False,
@@ -207,6 +234,7 @@ register_certificate(Certificate(
             "preserves vertex cuts and blocks; O(diameter) rounds",
     preserves=frozenset({"kappa2"}),
     build=sfs_certificate,
+    build_ex=counted(sfs_certificate_ex),
     load_state=_rescan_load(sfs_certificate),
     fold_state=_rescan_fold(sfs_certificate),
     lazy=True,
@@ -219,6 +247,7 @@ register_certificate(Certificate(
             "contracted diameter (sparse/path-like worlds)",
     preserves=frozenset({"kappa2"}),
     build=hybrid_certificate,
+    build_ex=counted(hybrid_certificate_ex),
     load_state=_rescan_load(hybrid_certificate),
     fold_state=_rescan_fold(hybrid_certificate),
     lazy=True,

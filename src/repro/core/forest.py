@@ -33,48 +33,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.graph.datastructs import INF32, INT, EdgeList
-from repro.kernels.boruvka_round.ops import (
-    boruvka_round,
-    boruvka_round_bytes,
-    frontier_round,
-    frontier_round_bytes,
-    kernel_path,
-)
+from repro.kernels.boruvka_round.ops import boruvka_round, frontier_round
 from repro.kernels.segment_min.ops import segment_min
-from repro.obs import get_tracer
-
-
-def _host_kernel_span(which: str, edges: EdgeList, use_pallas, impl):
-    """Run a jitted hooking impl under a measured ``kernel/forest/<which>``
-    span, then attach synthetic ``kernel/round/<which>`` children — one per
-    data-dependent round. Rounds run inside one XLA ``while_loop`` and are
-    invisible to host timers, so the children subdivide the measured parent
-    evenly and carry the analytic HBM byte model per round
-    (``kernels.boruvka_round.ops``) as ``model_bytes`` — wall-clock truth
-    at the parent, roofline attribution at the children (DESIGN.md
-    §Observability). No-op when tracing is disabled, and skipped when the
-    caller is itself inside a trace (certificates under jit), where host
-    timing is meaningless."""
-    tr = get_tracer()
-    if not tr.enabled or isinstance(edges.src, jax.core.Tracer):
-        return impl()
-    e = int(edges.src.shape[0])
-    path = kernel_path(use_pallas)
-    fused = path != "oracle"
-    bytes_fn = (boruvka_round_bytes if which == "boruvka"
-                else frontier_round_bytes)
-    with tr.span(f"kernel/forest/{which}", edges=e, path=path) as sp:
-        out = impl()
-        rounds = int(out[-1])  # host readback of the round-count scalar
-        sp.attrs["rounds"] = rounds
-        sp.sync(out)
-    if rounds > 0:
-        per = sp.dur / rounds
-        for i in range(rounds):
-            tr.add(f"kernel/round/{which}", sp.t0 + i * per, per,
-                   parent=sp.index, round=i,
-                   model_bytes=bytes_fn(e, fused))
-    return out
 
 
 def _ceil_log2(n: int) -> int:
@@ -96,8 +56,8 @@ def _forest_impl(src, dst, mask, n: int, init_labels=None,
     contains only edges that merge ACROSS the initial components — the
     incremental-merge primitive (see certificate.merge_certificates_
     incremental). Rounds are data-dependent (convergence-tested while loop,
-    bounded by log2(n)+2); the round count is returned for the measured
-    roofline model. ``use_pallas`` selects the per-round edge-scan backend
+    bounded by log2(n)+2); the round count is returned (the certificate
+    builders report it as ``certificate/rounds``). ``use_pallas`` selects the per-round edge-scan backend
     (``kernels.kernel_path``)."""
     E = src.shape[0]
     log_n = _ceil_log2(n)
@@ -161,10 +121,8 @@ def spanning_forest_ex(edges: EdgeList, init_labels=None,
     With ``init_labels`` the forest spans only the *contraction* of the
     initial partition by the edge set (edges internal to an initial
     component are never selected)."""
-    return _host_kernel_span(
-        "boruvka", edges, use_pallas,
-        lambda: _forest_impl(edges.src, edges.dst, edges.mask, edges.n_nodes,
-                             init_labels=init_labels, use_pallas=use_pallas))
+    return _forest_impl(edges.src, edges.dst, edges.mask, edges.n_nodes,
+                        init_labels=init_labels, use_pallas=use_pallas)
 
 
 def connected_components(edges: EdgeList, use_pallas: bool = False):
@@ -190,8 +148,8 @@ def _sfs_impl(src, dst, mask, n: int, comp_labels,
     arbitrary-forest Borůvka pair above provably is not.
 
     Rounds are data-dependent (one per BFS layer, O(diameter), convergence-
-    tested while loop bounded by n); the round count is returned for the
-    roofline model. Returns (forest bool[E], parent int[n], level int[n],
+    tested while loop bounded by n); the round count is returned.
+    Returns (forest bool[E], parent int[n], level int[n],
     root int[n], rounds).
     """
     E = src.shape[0]
@@ -253,7 +211,5 @@ def scan_first_forest_ex(edges: EdgeList, use_pallas: bool = False):
     `root_labels[v]` is the component's canonical minimum vertex id — the
     same partition as `connected_components`, canonicalized."""
     _, labels, _ = spanning_forest_ex(edges, use_pallas=use_pallas)
-    return _host_kernel_span(
-        "sfs", edges, use_pallas,
-        lambda: _sfs_impl(edges.src, edges.dst, edges.mask, edges.n_nodes,
-                          labels, use_pallas=use_pallas))
+    return _sfs_impl(edges.src, edges.dst, edges.mask, edges.n_nodes,
+                     labels, use_pallas=use_pallas)

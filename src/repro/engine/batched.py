@@ -131,10 +131,12 @@ def make_analysis_fn(n_nodes: int, kind: str = "bridges",
                      certificate: str | None = None):
     """The un-vmapped query core for one analysis kind, registry-driven.
 
-    ``(src, dst, mask) ->`` the kind's declared device buffers (see
-    ``Analysis.out_struct`` / DESIGN.md §Analysis registry), or — with
-    ``final='host'`` — the kind's sparse certificate, on which the caller
-    runs the kind's sequential host reference afterwards.
+    ``(src, dst, mask) -> (answer, rounds)``: the answer is the kind's
+    declared device buffers (see ``Analysis.out_struct`` / DESIGN.md
+    §Analysis registry), or — with ``final='host'`` — the kind's sparse
+    certificate, on which the caller runs the kind's sequential host
+    reference afterwards; ``rounds`` (int32[2]) counts the certificate's
+    forest rounds (``Certificate.build_ex``), zeros where none is built.
 
     Every kind follows the same registry-declared shape: pick the buffer
     the kind's ``device_input`` names (its certificate for the 2-edge
@@ -176,22 +178,20 @@ def make_analysis_fn(n_nodes: int, kind: str = "bridges",
             with jax.named_scope("stage/tombstone"):
                 mask, _ = tombstone_mask(src, dst, mask, *keys)
         buf = EdgeList(src, dst, mask, n_nodes)
+        rounds = jnp.zeros((2,), INT)
         if final == "host" or analysis.device_input == "certificate":
             with jax.named_scope(f"stage/certificate_build/{cert_label}"):
-                buf = certify(buf, capacity=cert_cap)
+                buf, rounds = certify(buf, capacity=cert_cap)
         if final == "host":
-            return buf.src, buf.dst, buf.mask
+            return (buf.src, buf.dst, buf.mask), rounds
         with jax.named_scope(f"stage/final/{analysis.kind}"):
             st = tour_state(buf.src, buf.dst, buf.mask, n_nodes)
-            return analysis.device_fn(buf.src, buf.dst, buf.mask, n_nodes,
-                                      st, out_cap)
+            with jax.named_scope("emit"):
+                out = analysis.device_fn(buf.src, buf.dst, buf.mask,
+                                         n_nodes, st, out_cap)
+        return out, rounds
 
     return one
-
-
-def make_query_fn(n_nodes: int, final: str = "device", on_trace=None):
-    """Backward-compatible alias: the kind='bridges' analysis core."""
-    return make_analysis_fn(n_nodes, "bridges", final, on_trace)
 
 
 def make_batched_pipeline(n_nodes: int, final: str = "device", on_trace=None,

@@ -180,7 +180,8 @@ def build_final_program(n_bucket: int, kind: str, on_trace):
         on_trace()
         with jax.named_scope(f"stage/final/{kind}"):
             st = tour_state(cs, cd, cm, n_bucket)
-            return analysis.device_fn(cs, cd, cm, n_bucket, st, out_cap)
+            with jax.named_scope("emit"):
+                return analysis.device_fn(cs, cd, cm, n_bucket, st, out_cap)
 
     return jax.jit(run)
 

@@ -43,8 +43,10 @@ that into the three properties a query-serving deployment needs:
   the fused one-shot programs), with a device-sync boundary so async
   device work is billed to the stage that launched it; the traced jaxprs
   carry matching ``jax.named_scope`` labels (DESIGN.md §Observability).
-  Tracing is off by default (``repro.obs.NULL_TRACER`` — a no-op) and
-  enabling it adds no retraces: spans live outside the traced functions
+  The one-shot programs also return their certificate's forest rounds,
+  counted as ``certificate/rounds`` while tracing is on. Tracing is off
+  by default (``repro.obs.NULL_TRACER`` — a no-op) and enabling it adds
+  no retraces: spans live outside the traced functions
   and appear in no cache key. ``snapshot()`` is the one rollup dict
   (cache counters + hit rate + live rebuild counters) serving code
   consumes.
@@ -409,9 +411,11 @@ class BridgeEngine:
         tr = get_tracer()
         with tr.span(f"engine/analyze/{kind}", substrate="single",
                      final=final):
-            with tr.span("stage/pad"):
+            with tr.span("stage/upload") as sp:
                 src = np.asarray(src, np.int32)
                 dst = np.asarray(dst, np.int32)
+                src, dst = sp.sync(jax.device_put((src, dst)))
+            with tr.span("stage/pad"):
                 n_bucket = self._bucket(n_nodes)
                 cap = self._bucket(max(len(src), 1))
                 el = EdgeList.from_arrays(src, dst, n_bucket, capacity=cap)
@@ -429,7 +433,9 @@ class BridgeEngine:
                     with_delete=kcap is not None, certificate=cert_name))
             with tr.span(f"stage/pipeline/{kind}", n_bucket=n_bucket,
                          cap=cap, certificate=cert_name) as sp:
-                out = sp.sync(fn(*args))
+                out, rounds = sp.sync(fn(*args))
+                if tr.enabled:  # the sum is device work: none with tracing off
+                    tr.count("certificate/rounds", rounds.sum())
             with tr.span("stage/convert"):
                 if final == "host":
                     return analysis.host_fn(*masked_arrays(out), n_nodes)
@@ -524,7 +530,10 @@ class BridgeEngine:
             with tr.span(f"stage/pipeline/{kind}", n_bucket=n_bucket,
                          cap=cap, batch=b_bucket,
                          certificate=cert_name) as sp:
-                out_dev = sp.sync(fn(*args))
+                out_dev, rounds = sp.sync(fn(*args))
+                if tr.enabled:  # the padding rows of the batch not counted
+                    tr.count("certificate/rounds",
+                             rounds[:len(graphs)].sum())
             with tr.span("stage/convert"):
                 stacked = (tuple(np.asarray(x) for x in out_dev)
                            if isinstance(out_dev, (tuple, list))
@@ -943,7 +952,8 @@ class BridgeEngine:
         The removed-count and per-certificate hit counts are the only host
         syncs in the delete path (the rebuild decision is host-side control
         flow): one small scalar readback per probed buffer — the full
-        buffer plus one per live certificate. Fusing them into one probe
+        buffer plus one per live certificate, each under a
+        ``host/readback`` span. Fusing them into one probe
         program is a possible future micro-optimization; the counters gate
         in ``scripts/check_bench.py`` pins today's program structure.
         """
@@ -960,8 +970,9 @@ class BridgeEngine:
             raise RuntimeError("no live graph: call load() first")
         live = self._live
         n_bucket = live.n_bucket
-        with get_tracer().span("engine/delete_edges", kind=kind,
-                               streamed=live.full is None):
+        tr = get_tracer()
+        with tr.span("engine/delete_edges", kind=kind,
+                     streamed=live.full is None):
             src = np.asarray(src, np.int32)
             dst = np.asarray(dst, np.int32)
             kcap = self._bucket(max(len(src), 1))
@@ -977,13 +988,16 @@ class BridgeEngine:
                 fs, fd, fm = live.full
                 fm, removed = self._delete_pass((fs, fd, fm), keys, "full")
                 live.full = (fs, fd, fm)
-                live.count -= int(removed)
+                with tr.span("host/readback", target="full"):
+                    live.count -= int(removed)
 
             for name, state in live.certs.items():
                 if state is None:
                     continue
                 _, hits = self._delete_pass(state[:3], keys, name)
-                if int(hits):
+                with tr.span("host/readback", target=name):
+                    hit = int(hits)
+                if hit:
                     live.rebuilds[name] += 1
                     live.certs[name] = (self._replay_state(name)
                                         if live.full is None else

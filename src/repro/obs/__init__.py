@@ -3,11 +3,14 @@
 Three pieces (DESIGN.md §Observability):
 
 * **Span tracer** (``tracer.py``) — nested wall-clock spans with
-  device-sync boundaries; Chrome-trace JSON + per-stage rollups. Off by
+  device-sync boundaries, and named counts the program reports (e.g.
+  ``certificate/rounds``); Chrome-trace JSON + per-stage rollups. Off by
   default: the module-level tracer is the no-op ``NULL_TRACER`` until
   ``enable_tracing()``; instrumented code always goes through
   ``get_tracer()`` so flipping the switch needs no re-plumbing (and adds
-  no retraces — spans wrap host dispatch only).
+  no retraces — spans wrap host dispatch only). ``disable_tracing()``
+  hands back the tracer it removes and ``last_tracer()`` keeps the most
+  recent one, so spans and counts outlive the session.
 
 * **Metrics registry** (``metrics.py``) — counters, gauges, fixed-bucket
   latency histograms with p50/p95/p99, one ``snapshot()`` dict. A
@@ -16,8 +19,10 @@ Three pieces (DESIGN.md §Observability):
   (tests, per-engine serving stats) construct their own.
 
 * **Profiler hooks** (``profile.py``) — the opt-in ``jax.profiler.trace``
-  capture whose on-device timeline lines up with the span names via the
-  ``jax.named_scope`` labels threaded through the pipeline jaxprs.
+  capture. Every live span is also a profiler annotation, so the capture
+  holds the engine's spans as host events on the profiler's clock, and
+  the device operations carry the matching ``jax.named_scope`` labels
+  (stages and their phases) in their HLO metadata.
 """
 from __future__ import annotations
 
@@ -38,6 +43,7 @@ from repro.obs.tracer import (
 )
 
 _TRACER: Tracer | NullTracer = NULL_TRACER
+_LAST_TRACER: Tracer | None = None
 _METRICS = MetricsRegistry()
 
 
@@ -50,16 +56,23 @@ def get_tracer() -> Tracer | NullTracer:
 
 def enable_tracing(tracer: Tracer | None = None) -> Tracer:
     """Install (and return) a live tracer as the process tracer."""
-    global _TRACER
-    _TRACER = tracer if tracer is not None else Tracer()
+    global _TRACER, _LAST_TRACER
+    _TRACER = _LAST_TRACER = tracer if tracer is not None else Tracer()
     return _TRACER
 
 
-def disable_tracing() -> None:
-    """Back to the no-op tracer (collected spans are dropped with it
-    unless the caller kept a reference)."""
+def disable_tracing() -> Tracer | NullTracer:
+    """Back to the no-op tracer; returns the tracer it removes
+    (``NULL_TRACER`` where tracing was already off)."""
     global _TRACER
-    _TRACER = NULL_TRACER
+    removed, _TRACER = _TRACER, NULL_TRACER
+    return removed
+
+
+def last_tracer() -> Tracer | None:
+    """The most recently enabled live tracer (current or already
+    disabled), or None if tracing was never enabled in this process."""
+    return _LAST_TRACER
 
 
 def get_metrics() -> MetricsRegistry:
@@ -88,6 +101,7 @@ __all__ = [
     "enable_tracing",
     "get_metrics",
     "get_tracer",
+    "last_tracer",
     "profiler_trace",
     "snapshot",
 ]

@@ -1,11 +1,15 @@
 """Opt-in on-device profiler capture (``jax.profiler.trace``).
 
 The span tracer times stages from the HOST side; this knob captures the
-matching DEVICE-side profile. Because every certificate build / merge /
-final-stage jaxpr is wrapped in a ``jax.named_scope`` carrying the same
-label as its host span (DESIGN.md §Observability has the taxonomy), an
-XProf/Perfetto capture from here maps 1:1 onto the span names in the
-Chrome trace — one run, two synchronized views of the same stages.
+DEVICE side in the same run. Both land on the profiler's clock: every
+live span (``obs.Tracer.span``) is also a ``jax.profiler.TraceAnnotation``,
+so the capture holds the engine's ``engine/*``, ``stage/*`` and
+``host/*`` spans as host events, and every device operation carries the
+``jax.named_scope`` labels of the stage (and phase) that launched it in
+its HLO op metadata (DESIGN.md §Observability has the taxonomy). The
+Chrome trace that ``Tracer.chrome_trace`` writes keeps its own
+``perf_counter`` clock; the profile is the view in which host spans and
+device operations can be lined up.
 
 Off by default and zero-cost when unused: the profiler is only started
 inside the context manager, and ``named_scope`` annotations are metadata

@@ -2,8 +2,8 @@
 
 The tracer answers the question the paper's cost decomposition poses —
 *which stage did the milliseconds go to?* — with zero dependencies beyond
-the standard library (jax is imported lazily, only when a span actually
-syncs a device value):
+the standard library (jax is imported lazily, only when a span is opened
+or syncs a device value):
 
 * ``Tracer.span(name, **attrs)`` opens a nested wall-clock span as a
   context manager. Calling ``sp.sync(out)`` inside the block makes the
@@ -11,23 +11,26 @@ syncs a device value):
   so asynchronously-dispatched device work is attributed to the stage
   that launched it instead of to whichever later host sync happens to
   absorb it (the *device_sync boundary* rule, DESIGN.md §Observability).
+  The span also enters a ``jax.profiler.TraceAnnotation`` of its name, so
+  a profiler capture taken meanwhile shows it as a host event on the
+  profiler's own clock, beside the device operations it launched.
 
-* ``Tracer.add(...)`` records a synthetic closed span — how the per-round
-  kernel spans are attached under a measured forest span whose rounds run
-  inside one XLA ``while_loop`` and are therefore invisible to host
-  timers (``core/forest.py``).
+* ``Tracer.count(name, value)`` adds ``int(value)`` to a named total
+  (``counts()``): what the program computes but no span can time, e.g.
+  the Borůvka rounds a certificate took inside one XLA ``while_loop``.
 
 * ``chrome_trace()`` exports the Chrome trace-event format (load the file
-  in ``chrome://tracing`` / Perfetto); ``rollup()`` folds the spans into
-  a per-name {count, total, self} table; ``stage_rollup()`` extracts the
-  outermost stage-classified spans — the per-stage cost table whose sum
-  is compared against end-to-end wall time (``benchmarks/run.py
-  --trace``).
+  in ``chrome://tracing`` / Perfetto), spans as complete events and counts
+  as counter events; ``rollup()`` folds the spans into a per-name
+  {count, total, self} table; ``stage_rollup()`` extracts the outermost
+  stage-classified spans — the per-stage cost table whose sum is compared
+  against end-to-end wall time (``benchmarks/run.py --trace``).
 
 A DISABLED tracer is the module-level ``NULL_TRACER`` singleton: every
-``span()`` call returns one shared no-op handle, ``add`` returns
-immediately, and no clock is read — instrumented hot paths pay one
-attribute lookup and one method call (bounded by
+``span()`` call returns one shared no-op handle, ``count`` returns
+without touching its value (a device scalar is never read back), and no
+clock is read — instrumented hot paths pay one attribute lookup and one
+method call (bounded by
 ``tests/test_obs.py::test_disabled_tracer_overhead``). Enabling tracing
 changes no program: spans wrap host-side dispatch only, so cache keys and
 traced computations are untouched (the no-retrace tests gate this).
@@ -41,10 +44,9 @@ import json
 import time
 
 #: name prefixes classified as *stages* for the per-stage rollup: device
-#: dispatch stages, kernel measurements, merge-schedule phases, and host
-#: pre/post-processing. Request-level ``engine/*`` spans are containers,
+#: dispatch stages, merge-schedule phases, and host pre/post-processing. Request-level ``engine/*`` spans are containers,
 #: not stages — their children carry the cost.
-STAGE_PREFIXES = ("stage/", "kernel/", "merge/", "host/")
+STAGE_PREFIXES = ("stage/", "merge/", "host/")
 
 
 class Span:
@@ -52,8 +54,7 @@ class Span:
 
     ``sp.sync(value)`` registers a device value (any pytree) to
     ``jax.block_until_ready`` at span close. ``sp.t0``/``sp.dur``/
-    ``sp.index`` are readable after the with-block (synthetic children are
-    attached to ``sp.index``).
+    ``sp.index`` are readable after the with-block.
     """
 
     __slots__ = ("tracer", "name", "attrs", "t0", "dur", "index", "depth",
@@ -98,8 +99,30 @@ class Span:
         return False
 
 
+class ProfiledSpan(Span):
+    """A span that is also a ``jax.profiler.TraceAnnotation`` of its name:
+    while a profiler capture runs, the span appears in it as a host event
+    on the profiler's clock (and costs one no-op annotation otherwise)."""
+
+    __slots__ = ("_annotation",)
+
+    def __enter__(self):
+        import jax
+
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            return super().__exit__(exc_type, exc, tb)
+        finally:
+            self._annotation.__exit__(exc_type, exc, tb)
+
+
 class Tracer:
-    """Collects spans; export via ``chrome_trace`` / ``rollup``."""
+    """Collects spans and counts; export via ``chrome_trace`` / ``rollup``
+    / ``counts``."""
 
     enabled = True
 
@@ -111,10 +134,13 @@ class Tracer:
         #: closed spans as dicts, slot-ordered by span START (index)
         self._spans: list[dict | None] = []
         self._stack: list[Span] = []
+        self._counts: dict[str, int] = {}
+        #: (name, clock time, running total) per ``count`` call
+        self._count_events: list[tuple[str, float, int]] = []
 
     # ------------------------------------------------------------ recording
     def span(self, name: str, **attrs) -> Span:
-        return Span(self, name, attrs)
+        return ProfiledSpan(self, name, attrs)
 
     #: a tracer is a callable: ``with tracer("stage/x"):`` == ``.span``
     __call__ = span
@@ -129,26 +155,27 @@ class Tracer:
             "parent": sp.parent, "index": sp.index, "attrs": sp.attrs,
         }
 
-    def add(self, name: str, t0: float, dur: float, *, parent: int = -1,
-            **attrs) -> None:
-        """Record a synthetic closed span (e.g. a per-round subdivision of
-        a measured kernel span). ``parent`` is a closed span's ``index``."""
-        depth = 0
-        if 0 <= parent < len(self._spans) and self._spans[parent]:
-            depth = self._spans[parent]["depth"] + 1
-        self._spans.append({
-            "name": name, "t0": t0, "dur": dur, "depth": depth,
-            "parent": parent, "index": len(self._spans), "attrs": attrs,
-        })
+    def count(self, name: str, value) -> None:
+        """Add ``int(value)`` to the total ``name`` (a device scalar is
+        read back here, so call it where the value is already synced)."""
+        total = self._counts.get(name, 0) + int(value)
+        self._counts[name] = total
+        self._count_events.append((name, self._clock(), total))
 
     # -------------------------------------------------------------- exports
     def spans(self) -> list[dict]:
         """Closed spans, start-ordered (open spans are excluded)."""
         return [s for s in self._spans if s is not None]
 
+    def counts(self) -> dict[str, int]:
+        """Totals of every ``count`` name since the last ``reset``."""
+        return dict(self._counts)
+
     def chrome_trace(self) -> dict:
-        """Chrome trace-event JSON object (``traceEvents`` complete
-        events; microsecond timestamps; span attrs under ``args``)."""
+        """Chrome trace-event JSON object (``traceEvents``: spans as
+        complete events with their attrs under ``args``, each ``count``
+        call as a counter event carrying the running total; microsecond
+        timestamps)."""
         events = [{
             "name": "process_name", "ph": "M", "pid": 0, "tid": 0,
             "args": {"name": "repro.obs"},
@@ -159,6 +186,9 @@ class Tracer:
                 "ts": s["t0"] * 1e6, "dur": s["dur"] * 1e6,
                 "args": {k: _jsonable(v) for k, v in s["attrs"].items()},
             })
+        for name, t, total in self._count_events:
+            events.append({"name": name, "ph": "C", "pid": 0, "tid": 0,
+                           "ts": t * 1e6, "args": {name: total}})
         return {"traceEvents": events, "displayTimeUnit": "ms"}
 
     def rollup(self) -> dict[str, dict]:
@@ -259,7 +289,7 @@ class NullTracer:
 
     __call__ = span
 
-    def add(self, *args, **kwargs) -> None:
+    def count(self, name: str, value) -> None:
         return None
 
     def reset(self) -> None:
@@ -267,6 +297,9 @@ class NullTracer:
 
     def spans(self) -> list:
         return []
+
+    def counts(self) -> dict:
+        return {}
 
     def chrome_trace(self) -> dict:
         return {"traceEvents": [], "displayTimeUnit": "ms"}

@@ -62,19 +62,6 @@ def test_span_lifo_enforced():
         a.__exit__(None, None, None)
 
 
-def test_synthetic_spans_attach_to_parent():
-    tr = Tracer()
-    with tr.span("kernel/forest/boruvka") as sp:
-        time.sleep(0.001)
-    tr.add("kernel/round/boruvka", sp.t0, sp.dur / 2, parent=sp.index,
-           round=0, model_bytes=900)
-    rounds = [s for s in tr.spans() if s["name"] == "kernel/round/boruvka"]
-    assert len(rounds) == 1
-    assert rounds[0]["parent"] == sp.index
-    assert rounds[0]["depth"] == 1
-    assert rounds[0]["attrs"]["model_bytes"] == 900
-
-
 def test_rollup_self_time_excludes_children():
     tr = Tracer()
     with tr.span("stage/parent"):
@@ -93,17 +80,17 @@ def test_stage_rollup_outermost_only():
         with tr.span("stage/pipeline"):     # outermost stage: counted
             with tr.span("stage/inner"):    # nested stage: not double-billed
                 pass
-    with tr.span("kernel/forest/boruvka"):  # stage at top level: counted
+    with tr.span("host/readback"):          # stage at top level: counted
         pass
     staged = tr.stage_rollup()
-    assert set(staged) == {"stage/pipeline", "kernel/forest/boruvka"}
+    assert set(staged) == {"stage/pipeline", "host/readback"}
 
 
 def test_chrome_trace_schema():
     tr = Tracer()
     with tr.span("stage/a", n=4, label="x"):
-        pass
-    tr.add("kernel/round/sfs", 0.0, 1e-3, round=0)
+        with tr.span("stage/b"):
+            pass
     doc = tr.chrome_trace()
     # must be valid JSON end to end
     doc2 = json.loads(json.dumps(doc))
@@ -268,26 +255,176 @@ def test_engine_snapshot_one_rollup():
     assert snap["live_graph_edges"] == eng.num_live_graph_edges
 
 
-def test_kernel_spans_with_round_subdivision():
-    """Host forest calls emit a measured kernel span whose synthetic
-    per-round children carry the analytic byte model."""
-    from repro.core.forest import spanning_forest_ex
-    from repro.graph.datastructs import EdgeList
-    from repro.kernels.boruvka_round.ops import boruvka_round_bytes, kernel_path
+# ------------------------------------------------ counts and the profiler clock
+def test_count_totals_and_chrome_counter_events():
+    tr = Tracer()
+    tr.count("certificate/rounds", 3)
+    tr.count("certificate/rounds", np.int32(5))
+    tr.count("other", 1)
+    assert tr.counts() == {"certificate/rounds": 8, "other": 1}
+    doc = json.loads(json.dumps(tr.chrome_trace()))
+    cs = [ev for ev in doc["traceEvents"] if ev["ph"] == "C"]
+    # one counter event per call, carrying the running total
+    assert [ev["args"] for ev in cs] == [{"certificate/rounds": 3},
+                                        {"certificate/rounds": 8},
+                                        {"other": 1}]
+    assert all(isinstance(ev["ts"], float) for ev in cs)
+    tr.reset()
+    assert tr.counts() == {}
+    assert not [ev for ev in tr.chrome_trace()["traceEvents"]
+                if ev["ph"] == "C"]
 
-    s, d = _graph(3)
+
+def test_null_tracer_count_never_converts():
+    """With tracing off a count must not read its value: on the engine's
+    path that would be a device readback."""
+
+    class Unreadable:
+        def __int__(self):
+            raise AssertionError("value read back with tracing off")
+
+    NULL_TRACER.count("certificate/rounds", Unreadable())
+    assert NULL_TRACER.counts() == {}
+    with pytest.raises(AssertionError, match="read back"):
+        Tracer().count("certificate/rounds", Unreadable())
+
+
+def test_disable_tracing_returns_tracer_and_last_tracer_keeps_it():
+    assert obs.disable_tracing() is NULL_TRACER  # nothing live to remove
+    live = obs.enable_tracing()
+    live.count("certificate/rounds", 2)
+    assert obs.last_tracer() is live
+    assert obs.disable_tracing() is live
+    assert obs.get_tracer() is NULL_TRACER
+    # the counts outlive the session
+    assert obs.last_tracer().counts() == {"certificate/rounds": 2}
+    newer = obs.enable_tracing(Tracer())
+    assert obs.last_tracer() is newer and newer is not live
+
+
+def _host_event_names(logdir) -> list[str]:
+    """Names of the host events of the newest profile under ``logdir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+
+    files = sorted(Path(logdir).rglob("*.xplane.pb"),
+                   key=lambda p: p.stat().st_mtime)
+    assert files, f"no profile written under {logdir}"
+    pd = ProfileData.from_serialized_xspace(files[-1].read_bytes())
+    return [ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_profiler_capture_holds_engine_spans(tmp_path):
+    """A live span is a profiler annotation: a CPU capture holds the
+    engine's spans as host events, once each."""
+    eng = BridgeEngine()
+    s, d = _graph(4)
+    eng.analyze(s, d, N)  # compile outside the capture
+    obs.enable_tracing()
+    with obs.profiler_trace(str(tmp_path)):
+        eng.analyze(s, d, N)
+    names = _host_event_names(tmp_path)
+    for want in ("engine/analyze/bridges", "stage/upload", "stage/pad",
+                 "stage/pipeline/bridges", "stage/convert"):
+        assert names.count(want) == 1, (want, sorted(set(names)))
+
+
+def test_serve_bridges_profile_holds_engine_spans(tmp_path):
+    """``serve_bridges --trace-out --profile-dir``: the device profile holds
+    the engine's ``engine/*`` and ``stage/*`` spans as host events, on the
+    profiler's clock, beside the Chrome trace."""
+    from repro.launch.serve_bridges import main
+
+    trace_out = tmp_path / "trace.json"
+    main(["--smoke", "--workload", "churn", "--delete-ratio", "1",
+          "--queries", "2", "--batch", "2", "--deltas", "1", "--n", "32",
+          "--edges", "128", "--trace-out", str(trace_out),
+          "--profile-dir", str(tmp_path / "profile")])
+    names = set(_host_event_names(tmp_path / "profile"))
+    assert {"engine/analyze/bridges", "engine/analyze_batch/bridges",
+            "engine/delete_edges", "stage/upload", "stage/pipeline/bridges",
+            "stage/tombstone", "host/readback", "stage/final/bridges"} <= names
+    chrome = json.loads(trace_out.read_text())["traceEvents"]
+    assert any(ev["name"] == "certificate/rounds" and ev["ph"] == "C"
+               for ev in chrome)
+
+
+def test_one_shot_rounds_match_certificate_rounds():
+    """The one-shot pipeline returns its certificate's forest rounds, and
+    the engine counts them while tracing is on: single, batched (padding
+    rows not counted), and zeros where no certificate is built."""
+    import jax
+
+    from repro.core.certificate import sparse_certificate_ex
+    from repro.engine import make_analysis_fn
+    from repro.graph.datastructs import EdgeList
+
+    s, d = _graph(5)
     el = EdgeList.from_arrays(s, d, N)
+    _, _, _, (r1, r2) = sparse_certificate_ex(el)
+    want = [int(r1), int(r2)]
+    _, rounds = jax.jit(make_analysis_fn(N, "bridges", "device"))(
+        el.src, el.dst, el.mask)
+    assert rounds.tolist() == want
+    _, rounds = jax.jit(make_analysis_fn(N, "cuts", "device"))(
+        el.src, el.dst, el.mask)
+    assert rounds.tolist() == [0, 0]
+
+    s2, d2 = _graph(6)
+    _, _, _, (q1, q2) = sparse_certificate_ex(EdgeList.from_arrays(s2, d2, N))
+    eng = BridgeEngine()
     tr = obs.enable_tracing()
-    _, _, rounds = spanning_forest_ex(el)
-    parents = [x for x in tr.spans() if x["name"] == "kernel/forest/boruvka"]
-    kids = [x for x in tr.spans() if x["name"] == "kernel/round/boruvka"]
-    assert len(parents) == 1
-    assert parents[0]["attrs"]["rounds"] == int(rounds)
-    assert len(kids) == int(rounds)
-    fused = kernel_path(None) != "oracle"
-    want_bytes = boruvka_round_bytes(el.capacity, fused)
-    assert all(k["attrs"]["model_bytes"] == want_bytes for k in kids)
-    assert all(k["parent"] == parents[0]["index"] for k in kids)
-    # subdivision spans the parent's measured duration
-    total_kid = sum(k["dur"] for k in kids)
-    assert total_kid == pytest.approx(parents[0]["dur"], rel=1e-6)
+    eng.analyze(s, d, N)
+    assert tr.counts() == {"certificate/rounds": sum(want)}
+    tr.reset()
+    eng.analyze_batch([(s, d), (s2, d2), (s, d)], N)  # padded to 4 rows
+    assert tr.counts() == {"certificate/rounds":
+                           2 * sum(want) + int(q1) + int(q2)}
+
+
+def _op_names(compiled_text: str) -> list[str]:
+    import re
+
+    return re.findall(r'op_name="([^"]*)"', compiled_text)
+
+
+def test_phase_scopes_cover_final_and_certificate_programs():
+    """In the compiled one-shot and final programs, every instruction of
+    the final stage carries one of its phases, and every Borůvka round of
+    the certificate one of its two forests."""
+    import re
+
+    import jax
+
+    from repro.core.certificate import certificate_capacity
+    from repro.engine.batched import make_analysis_fn
+    from repro.engine.dispatch import build_final_program
+
+    n, cap = 64, 256
+    cc = certificate_capacity(n)
+
+    def structs(c):
+        return (jax.ShapeDtypeStruct((c,), np.int32),
+                jax.ShapeDtypeStruct((c,), np.int32),
+                jax.ShapeDtypeStruct((c,), np.bool_))
+
+    one_shot = _op_names(jax.jit(make_analysis_fn(n, "bridges", "device"))
+                         .lower(*structs(cap)).compile().as_text())
+    final = _op_names(build_final_program(n, "bridges", lambda: None)
+                      .lower(*structs(cc)).compile().as_text())
+    phase = re.compile(r"stage/final/bridges/(tour/(forest|euler|lowhigh)"
+                       r"|emit)/")
+    for ops in (one_shot, final):
+        in_final = [o for o in ops if "stage/final/" in o]
+        assert in_final
+        assert [o for o in in_final if not phase.search(o)] == []
+        assert any("tour/forest" in o and "kernel/round/boruvka" in o
+                   for o in in_final)
+    rounds = [o for o in one_shot if "stage/certificate_build/" in o
+              and "kernel/round/boruvka" in o]
+    assert rounds
+    assert [o for o in rounds
+            if not re.search(r"/cert/forest[12]/", o)] == []
+    assert any("/cert/compact/" in o for o in one_shot)

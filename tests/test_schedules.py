@@ -84,7 +84,7 @@ def test_distributed_kind_matches_single_device_all_schedules(kind):
         answer_on = [0] if schedule == "paper" else [0, M - 1]
         for i in answer_on:
             c = certs[i]
-            got = analysis.to_result(final_fn(c.src, c.dst, c.mask), n)
+            got = analysis.to_result(final_fn(c.src, c.dst, c.mask)[0], n)
             if analysis.kind == "2ecc":
                 assert np.array_equal(got, want), (kind, schedule, i)
             else:
