@@ -94,10 +94,10 @@ def run(out, smoke: bool = False):
     # check_bench gate pins them exactly, the assert enforces the bound)
     ps = np.arange(PATH_N - 1, dtype=np.int32)
     el = EdgeList.from_arrays(ps, ps + 1, PATH_N)
-    _, _, _, (sr1, sr2) = sfs_certificate_ex(el)
+    _, _, _, (sr1, sr2), _ = sfs_certificate_ex(el)
     sfs_rounds = int(sr1) + int(sr2)
     t_hyb = timeit(lambda: hybrid_certificate_ex(el))
-    _, (hr0, hr1, hr2) = hybrid_certificate_ex(el)
+    _, (hr0, hr1, hr2), _ = hybrid_certificate_ex(el)
     hybrid_rounds = int(hr1) + int(hr2)
     assert hybrid_rounds * 4 <= sfs_rounds, \
         f"hybrid rounds {hybrid_rounds} not >=4x under sfs {sfs_rounds}"

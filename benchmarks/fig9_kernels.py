@@ -116,7 +116,7 @@ def run(out, smoke: bool = False):
     # means the hooking/contraction schedule changed, the regression the
     # roofline's calibrated model would silently absorb
     t_forest = timeit(lambda: spanning_forest_ex(el))
-    _, _, rounds = spanning_forest_ex(el)
+    _, _, rounds, _ = spanning_forest_ex(el)
     rounds = int(rounds)
     total_fused = rounds * bb_f
     out.append(csv_row(

@@ -51,7 +51,10 @@ def tour_state(src, dst, mask, n: int) -> dict:
     # jit it wraps (DESIGN.md §Observability): the forest, the tour, and
     # everything that derives low/high values from the tour
     with jax.named_scope("tour/forest"):
-        tree_mask, labels = spanning_forest(EdgeList(src, dst, mask, n))
+        # the plain loop: with the filtered one the TPU compiler schedules
+        # the Euler tour behind it slower than the loop saves (PERF.md §6)
+        tree_mask, labels = spanning_forest(EdgeList(src, dst, mask, n),
+                                            filtered=False)
 
     with jax.named_scope("tour/euler"):
         tour = euler_tour(

@@ -133,8 +133,9 @@ def get_analysis(kind: str) -> Analysis:
 
 def certificate_fn(certificate: str) -> Callable:
     """The certificate builder an analysis runs on: (EdgeList, capacity) ->
-    (EdgeList in a fixed 2(n−1)-slot buffer, int32[2] forest rounds),
-    resolved via ``core.certs`` (``Certificate.build_ex``)."""
+    (EdgeList in a fixed 2(n−1)-slot buffer, int32[2] forest rounds,
+    int32[2] of them full-width), resolved via ``core.certs``
+    (``Certificate.build_ex``)."""
     return get_certificate(certificate).build_ex
 
 

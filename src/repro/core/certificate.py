@@ -22,7 +22,7 @@ def certificate_capacity(n_nodes: int) -> int:
 
 def sparse_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList:
     """Compute the certificate and compact it into a 2(n−1)-slot buffer."""
-    cert, _, _, _ = sparse_certificate_ex(edges, capacity=capacity)
+    cert, *_ = sparse_certificate_ex(edges, capacity=capacity)
     return cert
 
 
@@ -33,7 +33,8 @@ def merge_certificates(a: EdgeList, b: EdgeList) -> EdgeList:
 
 
 def sparse_certificate_ex(edges: EdgeList, capacity: int | None = None):
-    """Certificate + the component labels of its two forests (+ rounds).
+    """Certificate + the component labels of its two forests (+ rounds,
+    and of those the full-width ones, per forest).
 
     The labels seed the INCREMENTAL merge below: they are the state that
     lets later phases skip re-certifying edges they already know about.
@@ -42,13 +43,13 @@ def sparse_certificate_ex(edges: EdgeList, capacity: int | None = None):
     """
     cap = certificate_capacity(edges.n_nodes) if capacity is None else capacity
     with jax.named_scope("cert/forest1"):
-        f1, lab1, r1 = spanning_forest_ex(edges)
+        f1, lab1, r1, w1 = spanning_forest_ex(edges)
     with jax.named_scope("cert/forest2"):
         rest = EdgeList(edges.src, edges.dst, edges.mask & ~f1, edges.n_nodes)
-        f2, lab2, r2 = spanning_forest_ex(rest)
+        f2, lab2, r2, w2 = spanning_forest_ex(rest)
     with jax.named_scope("cert/compact"):
         cert = compact_edges(edges, cap, keep=f1 | f2)
-    return cert, lab1, lab2, (r1, r2)
+    return cert, lab1, lab2, (r1, r2), (w1, w2)
 
 
 def sfs_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList:
@@ -63,12 +64,13 @@ def sfs_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList:
     union of two SFS certificates yields an SFS certificate of the union,
     so the same merge schedules serve the vertex-connectivity kinds.
     """
-    cert, _, _, _ = sfs_certificate_ex(edges, capacity=capacity)
+    cert, *_ = sfs_certificate_ex(edges, capacity=capacity)
     return cert
 
 
 def sfs_certificate_ex(edges: EdgeList, capacity: int | None = None):
-    """SFS certificate + F1's (parent, level) pair (+ BFS rounds per pass).
+    """SFS certificate + F1's (parent, level) pair (+ BFS rounds per pass,
+    twice: every BFS round scans the whole buffer, so all are full-width).
 
     parent/level are the live SFS forest state the engine keeps for
     incremental vertex-cut serving (DESIGN.md §Analysis registry)."""
@@ -83,7 +85,7 @@ def sfs_certificate_ex(edges: EdgeList, capacity: int | None = None):
                     edges.n_nodes)
     f2, _, _, _, r2 = scan_first_forest_ex(rest)
     cert = compact_edges(edges, cap, keep=f1 | f2)
-    return cert, parent, level, (r1, r2)
+    return cert, parent, level, (r1, r2), (r1, r2)
 
 
 def hybrid_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList:
@@ -121,19 +123,20 @@ def hybrid_certificate(edges: EdgeList, capacity: int | None = None) -> EdgeList
     up to 2 always, edge connectivity up to 2 on simple inputs; composes
     under union-then-recertify, so it rides every merge schedule.
     """
-    cert, _ = hybrid_certificate_ex(edges, capacity=capacity)
+    cert, *_ = hybrid_certificate_ex(edges, capacity=capacity)
     return cert
 
 
 def hybrid_certificate_ex(edges: EdgeList, capacity: int | None = None):
     """Hybrid certificate + per-pass round counts.
 
-    Returns ``(cert, (rounds_chain, rounds_f1, rounds_f2))`` where
+    Returns ``(cert, (rounds_chain, rounds_f1, rounds_f2), full)`` where
     ``rounds_chain`` counts the Borůvka hooking rounds of the chain
     contraction and ``rounds_f1``/``rounds_f2`` the BFS rounds of the two
     scan passes on the contracted buffer — the observable for "hybrid
     bounds SFS depth on path-like worlds" (benchmarks/fig7
-    ``path_world_rounds``)."""
+    ``path_world_rounds``); ``full`` the same three counts of full-width
+    rounds."""
     cap = certificate_capacity(edges.n_nodes) if capacity is None else capacity
     n = edges.n_nodes
     src, dst, mask = edges.src, edges.dst, edges.mask
@@ -144,7 +147,7 @@ def hybrid_certificate_ex(edges: EdgeList, capacity: int | None = None):
     low = deg <= 2
     interior = valid & low[src] & low[dst]
     chain = valid & (low[src] | low[dst])
-    _, labels, r_chain = spanning_forest_ex(
+    _, labels, r_chain, w_chain = spanning_forest_ex(
         EdgeList(src, dst, interior, n))
     csrc, cdst = labels[src], labels[dst]
     contracted = valid & ~interior
@@ -157,7 +160,7 @@ def hybrid_certificate_ex(edges: EdgeList, capacity: int | None = None):
     f2, _, _, _, r2 = scan_first_forest_ex(
         EdgeList(csrc, cdst, contracted & ~f1 & ~dup, n))
     cert = compact_edges(edges, cap, keep=chain | f1 | f2)
-    return cert, (r_chain, r1, r2)
+    return cert, (r_chain, r1, r2), (w_chain, r1, r2)
 
 
 # NOTE: the certificate-type table lives in the certificate registry
@@ -188,10 +191,12 @@ def merge_certificates_incremental(own: EdgeList, f1_labels, f2_labels,
     """
     cap = certificate_capacity(own.n_nodes)
     with jax.named_scope("cert/forest1"):
-        f1d, f1_labels, r1 = spanning_forest_ex(recv, init_labels=f1_labels)
+        f1d, f1_labels, r1, _ = spanning_forest_ex(recv,
+                                                   init_labels=f1_labels)
     with jax.named_scope("cert/forest2"):
         rest = EdgeList(recv.src, recv.dst, recv.mask & ~f1d, recv.n_nodes)
-        f2d, f2_labels, r2 = spanning_forest_ex(rest, init_labels=f2_labels)
+        f2d, f2_labels, r2, _ = spanning_forest_ex(rest,
+                                                   init_labels=f2_labels)
     with jax.named_scope("cert/compact"):
         keep_recv = EdgeList(recv.src, recv.dst, recv.mask & (f1d | f2d),
                              recv.n_nodes)

@@ -15,11 +15,13 @@ Each ``Certificate`` declares (DESIGN.md §Certificate registry):
   certificate pair in a fixed 2(n−1)-slot buffer. Used by the recertify
   merge phases (union-then-rebuild).
 * ``build_ex`` — the same builder with its forests' round counts:
-  ``(EdgeList, capacity=...) -> (certificate, int32[2])``, the rounds up
-  to and including the first forest and those of the second. It is the
-  type's ``*_ex`` builder viewed through ``counted`` (no second path).
-  Used by the one-shot pipelines, which return the counts beside the
-  answer (``certificate/rounds``, DESIGN.md §Observability).
+  ``(EdgeList, capacity=...) -> (certificate, int32[2], int32[2])``, the
+  rounds up to and including the first forest and those of the second,
+  then the same two counts of the rounds whose edge scan ran over the
+  whole buffer. It is the type's ``*_ex`` builder viewed through
+  ``counted`` (no second path). Used by the one-shot pipelines, which
+  return the counts beside the answer (``certificate/rounds`` and
+  ``certificate/full_rounds``, DESIGN.md §Observability).
 * ``load_state`` — ``(EdgeList, capacity) -> state``: the live-serving
   state, a flat tuple whose FIRST THREE leaves are the pair's
   ``(src, dst, mask)`` buffers and whose remaining leaves are whatever
@@ -74,7 +76,8 @@ class Certificate:
     """Descriptor for one sparse-certificate type (see module docstring).
 
     build      : (EdgeList, capacity=...) -> EdgeList
-    build_ex   : (EdgeList, capacity=...) -> (EdgeList, int32[2] rounds)
+    build_ex   : (EdgeList, capacity=...)
+                 -> (EdgeList, int32[2] rounds, int32[2] full rounds)
     load_state : (EdgeList, capacity) -> (src, dst, mask, *aux)
     fold_state : ((src, dst, mask, *aux), recv EdgeList, capacity) -> state
     """
@@ -163,14 +166,18 @@ def primary_certificate() -> str:
 # -------------------------------------------------------------- state glue
 def counted(build_ex: Callable) -> Callable:
     """``build_ex`` view of an ``*_ex`` builder, whose first output is the
-    certificate and whose last is its passes' round counts: the counts
-    fold into ``int32[2]`` — every pass before the second forest, then the
-    second forest (the hybrid's chain contraction joins its first)."""
+    certificate and whose last two are its passes' round counts and, of
+    those, the full-width ones: each folds into ``int32[2]`` — every pass
+    before the second forest, then the second forest (the hybrid's chain
+    contraction joins its first)."""
+
+    def fold(counts):
+        *first, second = counts
+        return jnp.stack([sum(first), second]).astype(INT)
 
     def build(edges: EdgeList, capacity: int | None = None):
         out = build_ex(edges, capacity=capacity)
-        *first, second = out[-1]
-        return out[0], jnp.stack([sum(first), second]).astype(INT)
+        return out[0], fold(out[-2]), fold(out[-1])
 
     return build
 
@@ -184,7 +191,7 @@ def _state_pair(state: tuple, n_nodes: int) -> EdgeList:
 
 
 def _warm_load(edges: EdgeList, capacity: int) -> tuple:
-    cert, lab1, lab2, _ = sparse_certificate_ex(edges, capacity=capacity)
+    cert, lab1, lab2, *_ = sparse_certificate_ex(edges, capacity=capacity)
     return (*_pair_state(cert), lab1, lab2)
 
 

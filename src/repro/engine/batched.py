@@ -131,12 +131,14 @@ def make_analysis_fn(n_nodes: int, kind: str = "bridges",
                      certificate: str | None = None):
     """The un-vmapped query core for one analysis kind, registry-driven.
 
-    ``(src, dst, mask) -> (answer, rounds)``: the answer is the kind's
-    declared device buffers (see ``Analysis.out_struct`` / DESIGN.md
+    ``(src, dst, mask) -> (answer, rounds, full)``: the answer is the
+    kind's declared device buffers (see ``Analysis.out_struct`` / DESIGN.md
     §Analysis registry), or — with ``final='host'`` — the kind's sparse
     certificate, on which the caller runs the kind's sequential host
     reference afterwards; ``rounds`` (int32[2]) counts the certificate's
-    forest rounds (``Certificate.build_ex``), zeros where none is built.
+    forest rounds and ``full`` (int32[2]) those of them whose edge scan ran
+    over the whole buffer (``Certificate.build_ex``), zeros where none is
+    built.
 
     Every kind follows the same registry-declared shape: pick the buffer
     the kind's ``device_input`` names (its certificate for the 2-edge
@@ -178,18 +180,18 @@ def make_analysis_fn(n_nodes: int, kind: str = "bridges",
             with jax.named_scope("stage/tombstone"):
                 mask, _ = tombstone_mask(src, dst, mask, *keys)
         buf = EdgeList(src, dst, mask, n_nodes)
-        rounds = jnp.zeros((2,), INT)
+        rounds = full = jnp.zeros((2,), INT)
         if final == "host" or analysis.device_input == "certificate":
             with jax.named_scope(f"stage/certificate_build/{cert_label}"):
-                buf, rounds = certify(buf, capacity=cert_cap)
+                buf, rounds, full = certify(buf, capacity=cert_cap)
         if final == "host":
-            return (buf.src, buf.dst, buf.mask), rounds
+            return (buf.src, buf.dst, buf.mask), rounds, full
         with jax.named_scope(f"stage/final/{analysis.kind}"):
             st = tour_state(buf.src, buf.dst, buf.mask, n_nodes)
             with jax.named_scope("emit"):
                 out = analysis.device_fn(buf.src, buf.dst, buf.mask,
                                          n_nodes, st, out_cap)
-        return out, rounds
+        return out, rounds, full
 
     return one
 

@@ -44,9 +44,10 @@ that into the three properties a query-serving deployment needs:
   device work is billed to the stage that launched it; the traced jaxprs
   carry matching ``jax.named_scope`` labels (DESIGN.md §Observability).
   The one-shot programs also return their certificate's forest rounds,
-  counted as ``certificate/rounds`` while tracing is on. Tracing is off
-  by default (``repro.obs.NULL_TRACER`` — a no-op) and enabling it adds
-  no retraces: spans live outside the traced functions
+  counted as ``certificate/rounds`` while tracing is on, and those of them
+  whose edge scan ran over the whole buffer, ``certificate/full_rounds``.
+  Tracing is off by default (``repro.obs.NULL_TRACER`` — a no-op) and
+  enabling it adds no retraces: spans live outside the traced functions
   and appear in no cache key. ``snapshot()`` is the one rollup dict
   (cache counters + hit rate + live rebuild counters) serving code
   consumes.
@@ -433,9 +434,10 @@ class BridgeEngine:
                     with_delete=kcap is not None, certificate=cert_name))
             with tr.span(f"stage/pipeline/{kind}", n_bucket=n_bucket,
                          cap=cap, certificate=cert_name) as sp:
-                out, rounds = sp.sync(fn(*args))
+                out, rounds, full = sp.sync(fn(*args))
                 if tr.enabled:  # the sum is device work: none with tracing off
                     tr.count("certificate/rounds", rounds.sum())
+                    tr.count("certificate/full_rounds", full.sum())
             with tr.span("stage/convert"):
                 if final == "host":
                     return analysis.host_fn(*masked_arrays(out), n_nodes)
@@ -530,10 +532,12 @@ class BridgeEngine:
             with tr.span(f"stage/pipeline/{kind}", n_bucket=n_bucket,
                          cap=cap, batch=b_bucket,
                          certificate=cert_name) as sp:
-                out_dev, rounds = sp.sync(fn(*args))
+                out_dev, rounds, full = sp.sync(fn(*args))
                 if tr.enabled:  # the padding rows of the batch not counted
                     tr.count("certificate/rounds",
                              rounds[:len(graphs)].sum())
+                    tr.count("certificate/full_rounds",
+                             full[:len(graphs)].sum())
             with tr.span("stage/convert"):
                 stacked = (tuple(np.asarray(x) for x in out_dev)
                            if isinstance(out_dev, (tuple, list))

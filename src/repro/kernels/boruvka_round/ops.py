@@ -12,6 +12,11 @@ substrate) picks its round implementation in one place.
   * ``True`` — the Pallas kernel: compiled on a TPU, run by the Pallas
     interpreter elsewhere (how the parity tests drive it on CPU).
 
+``boruvka_cross`` and ``boruvka_pick`` are the XLA round's two halves, the
+gathers and the scatter-mins, for a loop that keeps the gathered labels
+from one round to the next (``core/forest.py``'s filtered loop); the
+Pallas kernel has no such split.
+
 ``kernel_path(use_pallas)`` names what a setting runs (``oracle`` |
 ``pallas`` | ``interpret``); the ops dispatch through it, and serving
 reports and benchmark records carry it.
@@ -24,12 +29,15 @@ from repro.kernels.boruvka_round.kernel import (
     frontier_round_pallas,
 )
 from repro.kernels.boruvka_round.ref import (
+    boruvka_cross,
+    boruvka_pick,
     boruvka_round_ref,
     frontier_round_ref,
 )
 
-__all__ = ["boruvka_round", "boruvka_round_bytes", "frontier_round",
-           "frontier_round_bytes", "kernel_path"]
+__all__ = ["boruvka_cross", "boruvka_pick", "boruvka_round",
+           "boruvka_round_bytes", "frontier_round", "frontier_round_bytes",
+           "kernel_path"]
 
 
 def boruvka_round(src, dst, mask, labels, num_segments: int,

@@ -25,12 +25,26 @@ def boruvka_round_ref(src, dst, mask, labels, num_segments: int):
     Borůvka hooking reduction — distinct edge indices act as distinct
     weights.
     """
-    e = src.shape[0]
-    eidx = jnp.arange(e, dtype=INT)
+    lu, lv, cross = boruvka_cross(src, dst, mask, labels)
+    eidx = jnp.arange(src.shape[0], dtype=INT)
+    return boruvka_pick(eidx, cross, lu, lv, num_segments)
+
+
+def boruvka_cross(src, dst, mask, labels):
+    """The gather half of a Borůvka round: both endpoint labels of every
+    slot, ``(lu, lv)``, and which slots hold a cross edge (masked in, no
+    self-loop, endpoints in different components)."""
     lu = labels[src]
     lv = labels[dst]
-    cross = mask & (src != dst) & (lu != lv)
-    key = jnp.where(cross, eidx, INF32)
+    return lu, lv, mask & (src != dst) & (lu != lv)
+
+
+def boruvka_pick(keys, cross, lu, lv, num_segments: int):
+    """The scatter-min half of a Borůvka round: per component label, the
+    minimum key among the cross slots with an endpoint in it (INF32 where
+    none). ``keys`` are the slots' edge indices: ``arange(E)`` over a whole
+    buffer, the original indices over a compacted one."""
+    key = jnp.where(cross, keys, INF32)
     best_u = jax.ops.segment_min(key, lu, num_segments=num_segments)
     best_v = jax.ops.segment_min(key, lv, num_segments=num_segments)
     return jnp.minimum(best_u, best_v).astype(INT)

@@ -78,7 +78,7 @@ def test_incremental_merge_matches_recertify(seed):
         src_b, dst_b = gen.random_graph(n, max(len(src_b), 1), seed=seed + 7)
         el_b = EdgeList.from_arrays(src_b, dst_b, n, capacity=el_a.capacity)
     cap = certificate_capacity(n)
-    ca, lab1, lab2, _ = sparse_certificate_ex(el_a, capacity=cap)
+    ca, lab1, lab2, _, _ = sparse_certificate_ex(el_a, capacity=cap)
     cb = sparse_certificate(el_b, capacity=cap)
     merged, lab1, lab2, rounds = merge_certificates_incremental(
         ca, lab1, lab2, cb

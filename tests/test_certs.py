@@ -199,8 +199,8 @@ def test_hybrid_bfs_rounds_far_below_sfs_on_long_path():
     to nothing)."""
     s, d, n = _path_world(1024)
     el = EdgeList.from_arrays(s, d, n)
-    _, _, _, (sr1, sr2) = sfs_certificate_ex(el)
-    cert, (r_chain, hr1, hr2) = hybrid_certificate_ex(el)
+    _, _, _, (sr1, sr2), _ = sfs_certificate_ex(el)
+    cert, (r_chain, hr1, hr2), _ = hybrid_certificate_ex(el)
     sfs_rounds = int(sr1) + int(sr2)
     hybrid_rounds = int(hr1) + int(hr2)
     assert sfs_rounds >= n - 1          # one BFS layer per path vertex

@@ -150,9 +150,10 @@ def test_registry_out_struct_matches_traced_shapes():
                   jax.ShapeDtypeStruct((cap,), np.bool_))
     for kind in ANALYSIS_KINDS:
         analysis = get_analysis(kind)
-        got, rounds = jax.eval_shape(make_analysis_fn(n, kind, "device"),
-                                     *in_structs)
-        assert rounds.shape == (2,) and rounds.dtype == np.int32, kind
+        got, rounds, full = jax.eval_shape(
+            make_analysis_fn(n, kind, "device"), *in_structs)
+        for counts in (rounds, full):
+            assert counts.shape == (2,) and counts.dtype == np.int32, kind
         # out_struct's capacity = the buffer the final stage ran on
         ran_on = cert_cap if analysis.device_input == "certificate" else cap
         want = analysis.out_struct(n, ran_on)

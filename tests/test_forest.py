@@ -1,20 +1,30 @@
 """Spanning-forest properties: acyclic, component-spanning, label-correct —
 for both the Borůvka hooking forest and the scan-first-search (BFS-layer)
 frontier-hooking primitive."""
+import jax
 import networkx as nx
 import numpy as np
+import pytest
 
 from repro.core.forest import (
     connected_components,
+    narrow_slots,
     scan_first_forest,
     scan_first_forest_ex,
     spanning_forest,
+    spanning_forest_ex,
 )
 from repro.graph import generators as gen
 from repro.graph.datastructs import EdgeList
 
 from _hyp import given, st
-from helpers import bucketed_graph, to_graph
+from helpers import (
+    blob_chain,
+    boruvka_reference,
+    bucketed_graph,
+    kronecker_like,
+    to_graph,
+)
 
 
 def check_forest(src, dst, n, el):
@@ -175,8 +185,8 @@ def test_warm_start_forest_extends_to_union(seed):
     src_b, dst_b = gen.random_graph(n, max(len(src_a) // 2, 1), seed=seed + 3)
     el_b = EdgeList.from_arrays(src_b, dst_b, n)
 
-    fa, labels_a, _ = spanning_forest_ex(el_a)
-    fd, labels_u, rounds = spanning_forest_ex(el_b, init_labels=labels_a)
+    fa, labels_a, _, _ = spanning_forest_ex(el_a)
+    fd, labels_u, _, _ = spanning_forest_ex(el_b, init_labels=labels_a)
     fa, fd = np.asarray(fa), np.asarray(fd)
 
     fs = np.concatenate([src_a[fa[: len(src_a)] & np.asarray(el_a.mask)[: len(src_a)]],
@@ -191,3 +201,90 @@ def test_warm_start_forest_extends_to_union(seed):
     labels_u = np.asarray(labels_u)
     for comp in nx.connected_components(U):
         assert len({int(labels_u[v]) for v in comp}) == 1
+
+
+# ------------------------------------- filtered loop against the reference
+def _filtered_case(case):
+    """(src, dst, mask, n, init_labels) of one case, in a 2^16-slot buffer
+    (``narrow_slots`` 64: room for the compaction to engage)."""
+    cap = 1 << 16
+    if case == "blob_chain_compacts":
+        s, d, n = blob_chain(8, 64, 4000, seed=0)
+    elif case == "skewed_stops_at_zero":
+        s, d, n = kronecker_like(12, cap, seed=1)
+    elif case == "all_masked":
+        s, d, n = blob_chain(4, 32, 500, seed=2)
+    else:  # warm_start: a second edge set hooked onto a first's partition
+        s0, d0, n = blob_chain(8, 64, 200, seed=3)
+        s, d, _ = blob_chain(8, 64, 4000, seed=4)
+        _, init, _, _ = boruvka_reference(s0, d0, np.ones(len(s0), bool), n)
+    el = EdgeList.from_arrays(s, d, n, capacity=cap)
+    mask = np.asarray(el.mask) & (case != "all_masked")
+    return (np.asarray(el.src), np.asarray(el.dst), mask, n,
+            init if case == "warm_start" else None)
+
+
+@pytest.mark.parametrize("case", ["blob_chain_compacts",
+                                  "skewed_stops_at_zero", "all_masked",
+                                  "warm_start", "vmapped_below_threshold",
+                                  "vmapped_would_compact", "plain_asked_for"])
+def test_filtered_loop_matches_reference_bit_for_bit(case):
+    """The filtered Borůvka loop returns the (forest, labels, rounds) of a
+    plain numpy min-index Borůvka, bit for bit, and scans the whole buffer
+    in exactly the rounds before the first that finds at most
+    ``narrow_slots(E)`` cross edges. Under ``vmap`` the plain loop runs,
+    which scans the whole buffer in every round: on buffers with no narrow
+    slots (below 2^10) and on rows the filtered loop would compact. So it
+    does where the caller asks for it (``filtered=False``)."""
+    if case == "plain_asked_for":
+        src, dst, mask, n, _ = _filtered_case("blob_chain_compacts")
+        f, lab, r, full = spanning_forest_ex(EdgeList(src, dst, mask, n),
+                                             filtered=False)
+        want_f, want_l, want_r, _ = boruvka_reference(src, dst, mask, n)
+        assert np.array_equal(np.asarray(f), want_f)
+        assert np.array_equal(np.asarray(lab), want_l)
+        assert int(r) == int(full) == want_r
+        return
+    if case.startswith("vmapped"):
+        if case == "vmapped_below_threshold":
+            graphs = [bucketed_graph(seed)[3] for seed in (0, 3, 6)]
+            rows = [(np.asarray(g.src), np.asarray(g.dst), np.asarray(g.mask))
+                    for g in graphs]  # 64 slots
+            n = graphs[0].n_nodes
+        else:
+            cases = [_filtered_case(c) for c in
+                     ("blob_chain_compacts", "skewed_stops_at_zero",
+                      "all_masked")]
+            rows = [c[:3] for c in cases]
+            n = max(c[3] for c in cases)
+        src, dst, mask = (np.stack(col) for col in zip(*rows))
+        got = jax.vmap(lambda s, d, m: spanning_forest_ex(
+            EdgeList(s, d, m, n)))(src, dst, mask)
+        for i in range(len(rows)):
+            f, lab, r, full = (np.asarray(x[i]) for x in got)
+            want_f, want_l, want_r, _ = boruvka_reference(src[i], dst[i],
+                                                          mask[i], n)
+            assert np.array_equal(f, want_f)
+            assert np.array_equal(lab, want_l)
+            assert int(r) == int(full) == want_r
+        if case == "vmapped_would_compact":  # unbatched, row 0 compacts
+            _, _, r0, full0 = spanning_forest_ex(EdgeList(*rows[0], n))
+            assert int(full0) < int(r0)
+        return
+    src, dst, mask, n, init = _filtered_case(case)
+    el = EdgeList(src, dst, mask, n)
+    f, lab, r, full = spanning_forest_ex(el, init_labels=init)
+    want_f, want_l, want_r, live = boruvka_reference(src, dst, mask, n,
+                                                     init_labels=init)
+    assert np.array_equal(np.asarray(f), want_f)
+    assert np.array_equal(np.asarray(lab), want_l)
+    assert int(r) == want_r
+    k = narrow_slots(len(src))
+    narrow_at = next(i for i, x in enumerate(live) if x <= k)
+    assert int(full) == narrow_at
+    if case == "blob_chain_compacts":  # narrow rounds that still hook
+        assert 0 < live[narrow_at] and narrow_at <= want_r - 2
+    elif case == "skewed_stops_at_zero":  # only the check is spared
+        assert live[narrow_at] == 0 and narrow_at == want_r - 1
+    elif case == "all_masked":
+        assert (want_r, int(full)) == (1, 0)

@@ -232,8 +232,8 @@ def test_forest_pallas_equals_lax_on_failure_scenarios(idx):
     fused kernel is a drop-in for the three-pass lax sequence, bit for bit."""
     sc = gen.failure_scenarios()[idx]
     el = EdgeList.from_arrays(sc["src"], sc["dst"], sc["n"])
-    f_lax, l_lax, r_lax = spanning_forest_ex(el, use_pallas=False)
-    f_pal, l_pal, r_pal = spanning_forest_ex(el, use_pallas=True)
+    f_lax, l_lax, r_lax, _ = spanning_forest_ex(el, use_pallas=False)
+    f_pal, l_pal, r_pal, _ = spanning_forest_ex(el, use_pallas=True)
     assert np.array_equal(np.asarray(f_lax), np.asarray(f_pal))
     assert np.array_equal(np.asarray(l_lax), np.asarray(l_pal))
     assert int(r_lax) == int(r_pal)

@@ -19,6 +19,8 @@ from repro.obs import (
     default_latency_buckets,
 )
 
+from helpers import blob_chain, nx_bridges
+
 
 @pytest.fixture(autouse=True)
 def _no_tracer_leak():
@@ -363,25 +365,51 @@ def test_one_shot_rounds_match_certificate_rounds():
 
     s, d = _graph(5)
     el = EdgeList.from_arrays(s, d, N)
-    _, _, _, (r1, r2) = sparse_certificate_ex(el)
-    want = [int(r1), int(r2)]
-    _, rounds = jax.jit(make_analysis_fn(N, "bridges", "device"))(
+    _, _, _, (r1, r2), (w1, w2) = sparse_certificate_ex(el)
+    want, want_full = [int(r1), int(r2)], [int(w1), int(w2)]
+    # a buffer below 2^10 slots has no narrow rounds: only each forest's
+    # convergence check skips its full-width scatter-min
+    assert want_full == [r - 1 for r in want]
+    _, rounds, full = jax.jit(make_analysis_fn(N, "bridges", "device"))(
         el.src, el.dst, el.mask)
     assert rounds.tolist() == want
-    _, rounds = jax.jit(make_analysis_fn(N, "cuts", "device"))(
+    assert full.tolist() == want_full
+    _, rounds, full = jax.jit(make_analysis_fn(N, "cuts", "device"))(
         el.src, el.dst, el.mask)
-    assert rounds.tolist() == [0, 0]
+    assert rounds.tolist() == full.tolist() == [0, 0]
 
     s2, d2 = _graph(6)
-    _, _, _, (q1, q2) = sparse_certificate_ex(EdgeList.from_arrays(s2, d2, N))
+    _, _, _, (q1, q2), _ = sparse_certificate_ex(
+        EdgeList.from_arrays(s2, d2, N))
     eng = BridgeEngine()
     tr = obs.enable_tracing()
     eng.analyze(s, d, N)
-    assert tr.counts() == {"certificate/rounds": sum(want)}
+    assert tr.counts() == {"certificate/rounds": sum(want),
+                           "certificate/full_rounds": sum(want_full)}
     tr.reset()
-    eng.analyze_batch([(s, d), (s2, d2), (s, d)], N)  # padded to 4 rows
-    assert tr.counts() == {"certificate/rounds":
-                           2 * sum(want) + int(q1) + int(q2)}
+    # padded to 4 rows; under vmap the plain loop scans every round fully
+    eng.analyze_batch([(s, d), (s2, d2), (s, d)], N)
+    total = 2 * sum(want) + int(q1) + int(q2)
+    assert tr.counts() == {"certificate/rounds": total,
+                           "certificate/full_rounds": total}
+
+
+def test_full_rounds_counted_below_rounds_where_the_loop_compacts():
+    """``certificate/full_rounds`` counts the rounds whose scatter-min ran
+    over the whole buffer: never more than ``certificate/rounds``, and
+    fewer where the filtered loop stops at zero and compacts (a 2^16-slot
+    buffer: 4000 links among 8 blobs, padded with self-loops)."""
+    s, d, n = blob_chain(8, 64, 4000, seed=0)
+    loops = np.arange((1 << 16) - len(s), dtype=np.int32) % n
+    s, d = np.concatenate([s, loops]), np.concatenate([d, loops])
+    eng = BridgeEngine()
+    tr = obs.enable_tracing()
+    got = eng.analyze(s, d, n)
+    counts = tr.counts()
+    obs.disable_tracing()
+    assert got == nx_bridges(s, d, n)
+    rounds = counts["certificate/rounds"]
+    assert 0 < counts["certificate/full_rounds"] <= rounds - 3
 
 
 def _op_names(compiled_text: str) -> list[str]:
