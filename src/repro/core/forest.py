@@ -122,25 +122,27 @@ def _xla_loop(n: int, limit: int):
 
 def _hook(labels, forest, best, src, dst, n: int):
     """Hook every component along its picked edge (``best``, an edge index
-    or INF32) and flatten the labels: (labels, forest, hooked any)."""
-    E = forest.shape[0]
-    has = best < INF32
-    e = jnp.where(has, best, 0)
-    # O(n) gathers of the chosen edges' endpoint labels — the only
-    # post-reduction label reads (nothing E-sized after the round)
-    cu = labels[src[e]]
-    cv = labels[dst[e]]
-    comp = jnp.arange(n, dtype=INT)
-    other = jnp.where(cu == comp, cv, cu)
-    prop = jnp.where(has, other, comp)
-    # distinct edge keys => only 2-cycles possible; break them by id order
-    mutual = prop[prop] == comp
-    hook = has & (~mutual | (comp < prop))
-    parent = jnp.where(hook, prop, comp)
-    chosen = jnp.where(hook, e, E)  # E is an out-of-range sentinel
-    forest = forest.at[chosen].set(True, mode="drop")
-    parent = _shortcut(parent, _ceil_log2(n))
-    return parent[labels], forest, jnp.any(hook)
+    or INF32) and flatten the labels: (labels, forest, hooked any). The
+    O(n) work of a round, under the scope ``kernel/hook/boruvka``."""
+    with jax.named_scope("kernel/hook/boruvka"):
+        E = forest.shape[0]
+        has = best < INF32
+        e = jnp.where(has, best, 0)
+        # O(n) gathers of the chosen edges' endpoint labels — the only
+        # post-reduction label reads (nothing E-sized after the round)
+        cu = labels[src[e]]
+        cv = labels[dst[e]]
+        comp = jnp.arange(n, dtype=INT)
+        other = jnp.where(cu == comp, cv, cu)
+        prop = jnp.where(has, other, comp)
+        # distinct edge keys => only 2-cycles possible; break them by id order
+        mutual = prop[prop] == comp
+        hook = has & (~mutual | (comp < prop))
+        parent = jnp.where(hook, prop, comp)
+        chosen = jnp.where(hook, e, E)  # E is an out-of-range sentinel
+        forest = forest.at[chosen].set(True, mode="drop")
+        parent = _shortcut(parent, _ceil_log2(n))
+        return parent[labels], forest, jnp.any(hook)
 
 
 def _plain_loop(src, dst, valid, labels, n: int, limit: int,

@@ -1,6 +1,7 @@
 """chip_smoke.py refuses to run without a TPU, and the entry points' compile
 cache lands where ``launch/compile_cache.py`` says."""
 import os
+import re
 import subprocess
 import sys
 from pathlib import Path
@@ -14,12 +15,19 @@ from repro.launch.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
 REPO = Path(__file__).resolve().parent.parent
 
 
+CACHE_CONFIG = ("jax_compilation_cache_dir",
+                "jax_compilation_cache_include_metadata_in_key",
+                "jax_hlo_source_file_canonicalization_regex")
+
+
 @pytest.fixture
 def cache_dir_config():
-    """Restore jax_compilation_cache_dir after a test that may set it."""
-    before = jax.config.jax_compilation_cache_dir
+    """Restore the compile cache's settings after a test that may set
+    them."""
+    before = {k: getattr(jax.config, k) for k in CACHE_CONFIG}
     yield
-    jax.config.update("jax_compilation_cache_dir", before)
+    for k, v in before.items():
+        jax.config.update(k, v)
 
 
 @pytest.mark.parametrize("four_chips", [False, True])
@@ -57,7 +65,49 @@ def test_tpu_cache_goes_to_the_checkout(monkeypatch, cache_dir_config):
 
 def test_cpu_runs_keep_no_cache(monkeypatch, cache_dir_config):
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    before = jax.config.jax_compilation_cache_dir
+    before = {k: getattr(jax.config, k) for k in CACHE_CONFIG}
     assert jax.default_backend() == "cpu"
     assert use_compile_cache() is None
-    assert jax.config.jax_compilation_cache_dir == before
+    assert {k: getattr(jax.config, k) for k in CACHE_CONFIG} == before
+
+
+def _cache_key(scope: str) -> str:
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    def f(x):
+        with jax.named_scope(scope):
+            return x * 2
+
+    lowered = jax.jit(f).lower(jax.ShapeDtypeStruct((8,), np.int32))
+    return cache_key.get(lowered.compiler_ir("stablehlo"),
+                         np.array(jax.devices()[:1]),
+                         compiler.get_compile_options(1, 1),
+                         jax.devices()[0].client)
+
+
+@pytest.mark.parametrize("where", ["env", "checkout"])
+def test_cache_key_carries_the_scopes(monkeypatch, cache_dir_config, where):
+    """Wherever a cache is on, two programs that differ by a scope alone
+    get two entries, so a profile of the one never shows the other's
+    scopes; source paths are keyed relative to the checkout."""
+    if where == "env":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(compile_cache.jax, "default_backend",
+                            lambda: "tpu")
+    def keys():
+        # one call site: the key holds the traceback of the tracing call
+        return [_cache_key(scope) for scope in ("stage/a", "stage/a",
+                                                "stage/b")]
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    a, again, b = keys()
+    assert a == again == b  # JAX's default key leaves the scopes out
+    use_compile_cache()
+    a, again, b = keys()
+    assert a == again != b
+    regex = jax.config.jax_hlo_source_file_canonicalization_regex
+    assert re.sub(regex, "", str(REPO / "src/repro/core/forest.py")) == \
+        "src/repro/core/forest.py"

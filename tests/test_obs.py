@@ -456,3 +456,26 @@ def test_phase_scopes_cover_final_and_certificate_programs():
     assert [o for o in rounds
             if not re.search(r"/cert/forest[12]/", o)] == []
     assert any("/cert/compact/" in o for o in one_shot)
+
+
+def test_hook_scope_under_both_forests_of_the_certificate():
+    """The one-shot program's HLO metadata holds ``kernel/hook/boruvka``
+    under ``cert/forest1`` and under ``cert/forest2``, and the hook's
+    operations are outside the round scopes, which time the edge scan."""
+    import jax
+
+    from repro.engine.batched import make_analysis_fn
+
+    n, cap = 64, 256
+    structs = (jax.ShapeDtypeStruct((cap,), np.int32),
+               jax.ShapeDtypeStruct((cap,), np.int32),
+               jax.ShapeDtypeStruct((cap,), np.bool_))
+    hooks = [o for o in _op_names(
+        jax.jit(make_analysis_fn(n, "bridges", "device"))
+        .lower(*structs).compile().as_text())
+        if "kernel/hook/boruvka" in o]
+    for forest in ("/cert/forest1/", "/cert/forest2/"):
+        assert any("stage/certificate_build/" in o and forest in o
+                   for o in hooks), forest
+    assert [o for o in hooks if "kernel/round/" in o
+            or "kernel/narrow/" in o] == []
