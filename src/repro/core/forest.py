@@ -9,7 +9,8 @@ Borůvka-style minimum-edge hooking with pointer-doubling contraction:
        labels)
     2. components hook along the picked edge; mutual 2-cycles (the only
        possible cycles under distinct edge keys) are broken by id order
-    3. labels are flattened by pointer doubling
+    3. labels are flattened by pointer doubling, until no label moves
+       (at most ceil(log2 V) steps)
 
 Each selected edge that survives hooking joins the forest. Distinct edge
 indices act as distinct weights, so the classic Borůvka argument gives an
@@ -53,10 +54,23 @@ def _ceil_log2(n: int) -> int:
 
 
 def _shortcut(parent: jax.Array, steps: int) -> jax.Array:
-    """Full pointer-doubling path compression."""
-    def body(_, p):
-        return p[p]
-    return lax.fori_loop(0, steps, body, parent)
+    """Full pointer-doubling path compression: ``p = p[p]`` until a step
+    moves no label, at most ``steps`` times (enough for any path shorter
+    than 2^steps). The answer is the fixed-step loop's, bit for bit: once
+    a step moves nothing, every later one would move nothing either.
+    Under the scope ``kernel/hook/shortcut``."""
+    def cond(state):
+        i, _ = state
+        return i < steps
+
+    def body(state):
+        i, p = state
+        q = p[p]
+        return jnp.where(jnp.all(q == p), steps, i + 1), q
+
+    with jax.named_scope("kernel/hook/shortcut"):
+        _, parent = lax.while_loop(cond, body, (jnp.int32(0), parent))
+    return parent
 
 
 def narrow_slots(e: int) -> int:

@@ -7,6 +7,8 @@ import numpy as np
 import pytest
 
 from repro.core.forest import (
+    _ceil_log2,
+    _shortcut,
     connected_components,
     narrow_slots,
     scan_first_forest,
@@ -288,3 +290,56 @@ def test_filtered_loop_matches_reference_bit_for_bit(case):
         assert live[narrow_at] == 0 and narrow_at == want_r - 1
     elif case == "all_masked":
         assert (want_r, int(full)) == (1, 0)
+
+
+# ------------------------------------------ pointer doubling to convergence
+def _parents(case, n, seed=0):
+    """Parent pointers of one rooted forest over ``n`` vertices (roots
+    point at themselves), ids permuted."""
+    rng = np.random.default_rng(seed)
+    order = rng.permutation(n)
+    if case == "random_forest":  # each vertex under an earlier one, or a root
+        up = np.array([rng.integers(0, i) if i and rng.random() < 0.97
+                       else i for i in range(n)])
+    elif case == "path":  # depth n - 1
+        up = np.maximum(np.arange(n) - 1, 0)
+    elif case == "star":
+        up = np.zeros(n, np.int64)
+    else:  # identity
+        up = np.arange(n)
+    p = np.empty(n, np.int32)
+    p[order] = order[up]
+    return p
+
+
+def _fixed_steps(p, steps):
+    for _ in range(steps):
+        p = p[p]
+    return p
+
+
+@pytest.mark.parametrize("case", ["random_forest", "path", "star",
+                                  "identity", "vmapped"])
+def test_shortcut_matches_fixed_step_compression(case):
+    """``_shortcut`` stops once a step moves no label, and returns what
+    ``ceil(log2 n)`` fixed pointer-doubling steps return, bit for bit: on
+    a path of depth n − 1, which needs every one of those steps, and under
+    ``vmap`` on rows that converge after different numbers of steps."""
+    n = 1 << 10
+    steps = _ceil_log2(n)
+    if case == "vmapped":
+        rows = np.stack([_parents(c, n, seed=i) for i, c in enumerate(
+            ("random_forest", "path", "star", "identity"))])
+        got = np.asarray(jax.vmap(lambda p: _shortcut(p, steps))(rows))
+        for row, out in zip(rows, got):
+            assert np.array_equal(out, _fixed_steps(row, steps))
+        return
+    p = _parents(case, n)
+    got = np.asarray(_shortcut(p, steps))
+    want = _fixed_steps(p, steps)
+    assert got.dtype == want.dtype
+    assert np.array_equal(got, want)
+    assert np.array_equal(want, want[want])  # a full compression
+    if case == "path":  # one step fewer leaves it unfinished
+        short = _fixed_steps(p, steps - 1)
+        assert not np.array_equal(short, short[short])
